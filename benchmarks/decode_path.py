@@ -55,6 +55,8 @@ import numpy as np
 from repro.core import cache as C
 from repro.kernels import ops
 from repro.kernels.runtime import on_tpu
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import device_info
 
 
 def build_cache(rng, layout: str, B: int, Hkv: int, D: int, S: int,
@@ -131,6 +133,7 @@ def main():
                          "longest context (see module docstring)")
     ap.add_argument("--out", default="BENCH_decode.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         # CI gate runs the production layout of the paper's TPU path plus
         # the maximal-ratio huffman layout (its fused-vs-blockwise win is
@@ -186,6 +189,7 @@ def main():
         l: geomean(xs) for l, xs in speedups.items()}
     bench["layout_geomean_fused_vs_blockwise"] = {
         l: geomean(xs) for l, xs in fused_ratios.items()}
+    bench["device"] = device_info()
     Path(args.out).write_text(json.dumps(bench, indent=2))
     print("per-layout geomean production speedup: " + "  ".join(
         f"{l} x{x:.2f}" for l, x in bench["layout_geomean_speedup"].items()))

@@ -33,6 +33,8 @@ import numpy as np
 
 from repro import obs
 from repro.core import pool as blockpool
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import device_info
 from repro.models import model as M
 from repro.models import registry
 from repro.serve.scheduler import Request, Server, ServerConfig
@@ -94,6 +96,7 @@ def main():
                          "for a compressing layout (CI gate)")
     ap.add_argument("--out", default="BENCH_pool.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 8)
         args.budgets = "2"
@@ -147,6 +150,7 @@ def main():
         # registry-sourced columns for run.py's CSV (last paged leg)
         bench["metrics"] = paged["metrics"]
 
+    bench["device"] = device_info()
     Path(args.out).write_text(json.dumps(bench, indent=2))
     print(f"wrote {args.out}")
     if args.require_capacity_win:

@@ -42,6 +42,8 @@ import jax
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import device_info
 from repro.models import model as M
 from repro.models import registry
 from repro.serve.scheduler import Request, Server, ServerConfig
@@ -113,6 +115,7 @@ def main():
                          "FLOPs at bit-identical greedy tokens (CI gate)")
     ap.add_argument("--out", default="BENCH_prefix.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 6)
         args.shared_blocks = min(args.shared_blocks, 8)
@@ -155,6 +158,7 @@ def main():
     bench["metrics"] = bench["modes"]["on"]["metrics"]
     print(f"bit_identical={identical}  prefill_flops_saved=x{saved:.2f}")
 
+    bench["device"] = device_info()
     Path(args.out).write_text(json.dumps(bench, indent=2))
     print(f"wrote {args.out}")
     if args.require_savings:

@@ -18,6 +18,7 @@ import subprocess
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import BENCH_COLUMNS
 
 SUITES = [
@@ -74,6 +75,7 @@ def run_one(mod_name: str) -> int:
     (``main()`` + ``--smoke``) that write their own ``BENCH_*.json`` — those
     run under ``--smoke`` and report one pass/fail CSV row here.
     """
+    enable_compile_cache()
     empty = "," * len(BENCH_COLUMNS)
     mod = __import__(f"benchmarks.{mod_name}", fromlist=["run"])
     if hasattr(mod, "run"):
@@ -116,6 +118,9 @@ def main() -> None:
                 print(f"{mod_name}_FAILED,0,{type(e).__name__}:{e}"
                       + "," * len(BENCH_COLUMNS), flush=True)
         else:
+            # One child per suite, and only the child touches a device: this
+            # parent imports jax (through repro.obs) but never initialises a
+            # backend, so on a TPU host the chip stays free for the child.
             code = (
                 "from benchmarks.run import run_one; "
                 f"run_one({mod_name!r})"

@@ -39,6 +39,8 @@ import jax
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import device_info
 from repro.models import model as M
 from repro.models import registry
 from repro.serve.engine import EngineConfig, LockstepEngine, Request
@@ -185,6 +187,7 @@ def main():
                          "measured layout runs into this directory")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         # small counts, same traffic shape as the default: prompts spanning
         # several length buckets (fragmented legacy groups), a deep scattered
@@ -287,6 +290,7 @@ def main():
           f"({p99_ratio:.2f}x better) at {tok_ratio:.2f}x tok/s, "
           f"bit_identical={match}")
 
+    bench["device"] = device_info()
     Path(args.out).write_text(json.dumps(bench, indent=2))
     print(f"aggregate speedup {agg:.2f}x; wrote {args.out}")
     if args.require_speedup and agg <= 1.0:

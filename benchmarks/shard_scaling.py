@@ -12,7 +12,10 @@ parity tests assert exactly that).
 
 Device counts are simulated: each N runs in a child process launched with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag must be
-set before jax initializes, hence the subprocess).
+set before jax initializes, hence the subprocess).  That flag only splits
+the CPU host platform, so on a TPU host the child refuses rather than
+measure one chip under a 1/2/4-device label; the sharded path on real
+chips is ``python chip_smoke.py --chips 4``.
 
 Writes ``BENCH_shard.json``.  ``--require-capacity-win`` exits non-zero
 unless the largest mesh admits at least 2x the concurrent requests of the
@@ -43,11 +46,21 @@ def child_main(args) -> None:
 
     from repro import obs
     from repro.core import pool as blockpool
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_serve_mesh
+    from repro.launch.serve import device_info
     from repro.models import model as M
     from repro.models import registry
     from repro.serve.scheduler import Request, Server, ServerConfig
 
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"shard_scaling simulates device counts on the CPU host platform "
+            f"(--xla_force_host_platform_device_count) but JAX found a "
+            f"{jax.default_backend()!r} backend; it does not measure chips. "
+            f"Run the sharded path on real chips with: python chip_smoke.py "
+            f"--chips 4")
+    enable_compile_cache()
     n = args.child
     cfg = dataclasses.replace(registry.get_smoke_config(args.arch),
                               cache_layout=args.layout, cache_block=8)
@@ -94,6 +107,7 @@ def child_main(args) -> None:
         "per_device_budget_bytes": per_device,
         "shards": st["shards"]["per_shard"],
         "metrics": obs.bench_columns(server),
+        "device": device_info(),
     }
     print(json.dumps(out))
 
@@ -161,6 +175,7 @@ def main():
     bench["tok_s_ratio"] = last["tok_s"] / first["tok_s"]
     # registry-sourced columns for run.py's CSV (largest mesh's run)
     bench["metrics"] = last["metrics"]
+    bench["device"] = last["device"]
     Path(args.out).write_text(json.dumps(bench, indent=2))
     print(f"wrote {args.out}  capacity x{bench['capacity_ratio']:.2f} "
           f"({counts[0]} -> {counts[-1]} devices)")

@@ -13,6 +13,8 @@ Layouts
 * ``pack_bits`` / ``unpack_bits`` — static bit-width b ∈ [1, 8]; codes are
   packed LSB-first into little-endian u32 words along the last axis.  Static
   shapes; straddling words is handled (b need not divide 32).
+* ``pack_planar`` / ``unpack_planar`` — the cache layouts' block format:
+  static width, no code straddles a word, one column per word (below).
 * ``choose_bits`` — per-block adaptive width (pow2-rounded option for the
   Pallas kernel's lax.switch dispatch).
 * ``pack_adaptive`` / ``unpack_adaptive`` — ragged multi-block container with
@@ -90,11 +92,15 @@ def unpack_bits(words: Array, bits: int, n_codes: int) -> Array:
 
 
 # ---------------------------------------------------------------------------
-# No-straddle layout: each u32 word holds floor(32/bits) whole codes.
+# Planar no-straddle layout: each u32 word holds floor(32/bits) whole codes
+# of ONE column.  A [T, D] block packs into [R, D] words, R = ceil(T / cpw):
+# word[r, d] carries code[i*R + r, d] at bit i*bits for every plane i.
 #
-# Wastes (32 mod bits) pad bits per word (e.g. 2/32 = 6.25% at b=5) but makes
-# unpacking gather-free: a reshape + broadcast shift + mask, which is exactly
-# what a TPU VPU wants and what the Pallas fused kernel uses per VMEM tile.
+# Wastes (32 mod bits) pad bits per word plus the rows that round T up to
+# cpw*R, but unpacking is one shift + mask per plane and a row concatenation
+# — lane-dense [R, D] vector ops with no gather and no lane reshape, which is
+# what Mosaic lowers inside the fused kernel and what XLA vectorizes
+# outside it.  The same functions run in both places.
 # ---------------------------------------------------------------------------
 
 
@@ -102,49 +108,40 @@ def codes_per_word(bits: int) -> int:
     return 32 // bits
 
 
-def nostraddle_words(n_codes: int, bits: int) -> int:
-    return (n_codes + codes_per_word(bits) - 1) // codes_per_word(bits)
+def planar_rows(n_rows: int, bits: int) -> int:
+    """Word rows that hold ``n_rows`` code rows at ``bits`` bits each."""
+    return (n_rows + codes_per_word(bits) - 1) // codes_per_word(bits)
 
 
-def pack_nostraddle(codes: Array, bits: int) -> Array:
-    """[..., L] uint8 -> [..., nostraddle_words(L, bits)] uint32."""
+def pack_planar(codes: Array, bits: int) -> Array:
+    """[..., T, D] codes (< 2**bits) -> [..., planar_rows(T, bits), D] u32."""
     if not (1 <= bits <= 16):
         raise ValueError(f"bits must be in [1, 16], got {bits}")
-    *lead, L = codes.shape
-    cpw = codes_per_word(bits)
-    W = nostraddle_words(L, bits)
-    pad = W * cpw - L
+    *lead, T, D = codes.shape
+    R = planar_rows(T, bits)
+    n_planes = -(-T // R)
     c = codes.astype(jnp.uint32) & jnp.uint32((1 << bits) - 1)
-    if pad:
-        c = jnp.concatenate([c, jnp.zeros((*lead, pad), jnp.uint32)], axis=-1)
-    c = c.reshape(*lead, W, cpw)
-    shifts = jnp.asarray(np.arange(cpw) * bits, dtype=jnp.uint32)
-    return jnp.sum(c << shifts, axis=-1).astype(jnp.uint32)
+    if n_planes * R > T:  # zero rows complete the last plane
+        c = jnp.concatenate(
+            [c, jnp.zeros((*lead, n_planes * R - T, D), jnp.uint32)], axis=-2)
+    words = c[..., :R, :]
+    for i in range(1, n_planes):
+        words = words | (c[..., i * R:(i + 1) * R, :] << jnp.uint32(i * bits))
+    return words
 
 
-def unpack_nostraddle(words: Array, bits: int, n_codes: int) -> Array:
-    """Inverse of pack_nostraddle — reshape/shift/mask only, no gathers."""
-    *lead, W = words.shape
-    cpw = codes_per_word(bits)
-    shifts = jnp.asarray(np.arange(cpw) * bits, dtype=jnp.uint32)
-    vals = (words[..., None] >> shifts) & jnp.uint32((1 << bits) - 1)
-    vals = vals.reshape(*lead, W * cpw)
-    return vals[..., :n_codes].astype(jnp.uint8)
+def unpack_planar(words: Array, bits: int, n_rows: int) -> Array:
+    """Inverse of ``pack_planar``: [..., R, D] u32 -> [..., n_rows, D] int32.
 
-
-def unpack_nostraddle_tile(words: Array, bits: int, n_codes: int) -> Array:
-    """No-straddle unpack of one flat [W] u32 tile -> [n_codes] uint32.
-
-    Same math as ``unpack_nostraddle`` but the shift table is a
-    ``broadcasted_iota`` generated in-graph, so the function is safe inside a
-    Pallas kernel body (a captured host array would lower as a Mosaic
-    constant).  This is the decode the fused attention kernel runs per VMEM
-    tile; layouts hand it to the kernel through their ``tile_decode`` hook.
+    int32 (not uint32) so callers convert to float with a cast Mosaic
+    lowers; kernel-safe as written (no captured arrays, no gathers).
     """
-    cpw = codes_per_word(bits)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, cpw), 1) * jnp.uint32(bits)
-    vals = (words[:, None] >> shifts) & jnp.uint32((1 << bits) - 1)
-    return vals.reshape(-1)[:n_codes]
+    R = words.shape[-2]
+    mask = jnp.uint32((1 << bits) - 1)
+    planes = [((words >> jnp.uint32(i * bits)) & mask).astype(jnp.int32)
+              for i in range(-(-n_rows // R))]
+    out = planes[0] if len(planes) == 1 else jnp.concatenate(planes, axis=-2)
+    return out[..., :n_rows, :]
 
 
 def choose_bits(codes: Array, axes: tuple[int, ...], pow2: bool = False) -> Array:

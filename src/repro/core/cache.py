@@ -147,10 +147,11 @@ class CacheSpec:
         return self.pool_pages if self.paged else self.n_blocks
 
     def words_k(self, head_dim: int) -> int:
-        return bitpack.nostraddle_words(self.block_size * head_dim, self.bits_k)
+        """u32 words of one packed K block (planar: rows × head_dim)."""
+        return bitpack.planar_rows(self.block_size, self.bits_k) * head_dim
 
     def words_v(self, head_dim: int) -> int:
-        return bitpack.nostraddle_words(self.block_size * head_dim, self.bits_v)
+        return bitpack.planar_rows(self.block_size, self.bits_v) * head_dim
 
 
 @jax.tree_util.register_pytree_with_keys_class
@@ -160,10 +161,10 @@ class LayerKVCache:
 
     Store shapes are layout-owned (see ``CacheLayout.init_store``); e.g. the
     packed layouts use
-      k_store : u32 [B, Hkv, NB, Wk]       (bit-packed block codes)
-      k_min/k_step : bf16 [B, Hkv, NB, D]  (BlockQuant units)
-      v_store : u32 [B, Hkv, NB, Wv]
-      v_min/v_step : bf16 [B, Hkv, NB, T]  (TokenQuant units; T = block_size)
+      k_store : u32 [B, Hkv, NB, Rk, D]       (planar bit-packed block codes)
+      k_min/k_step : bf16 [B, Hkv, NB, 1, D]  (BlockQuant units)
+      v_store : u32 [B, Hkv, NB, Rv, D]
+      v_min/v_step : bf16 [B, Hkv, NB, 1, T]  (TokenQuant units; T = block_size)
     while the raw layout stores bf16 [B, Hkv, NB, T, D] blocks with dummy
     scales.  Shared, layout-independent:
       k_buf / v_buf : bf16 [B, Hkv, T, D] — raw append buffer (residual window)

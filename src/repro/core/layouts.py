@@ -34,11 +34,11 @@ A layout owns four responsibilities:
                         the roofline model.
 
 Built-in layouts: ``raw`` (bf16, exact), ``packed`` (error-bounded quantizer
-+ no-straddle bit-packing), ``kivi`` (fixed-bit baseline), and ``huffman``
-(the paper's maximal-ratio path promoted to a servable layout: per-block
-Huffman streams with u16 per-stream bit counts, decoded by the chunked
-direct-lookup decoder — in VMEM inside the fused kernel and in the
-blockwise XLA floor alike).  Register new ones with
++ planar no-straddle bit-packing), ``kivi`` (fixed-bit baseline), and
+``huffman`` (the paper's maximal-ratio path promoted to a servable layout:
+per-block Huffman streams with u16 per-stream bit counts, decoded by the
+chunked direct-lookup decoder — in the blockwise XLA floor everywhere, and
+in VMEM inside the fused kernel off-TPU only).  Register new ones with
 ``@register_layout("name")``.
 """
 
@@ -178,6 +178,27 @@ def quant_block_minmax(x: Array, rel_scale: float, bits: int,
     return codes, jnp.squeeze(mn, unit_axes), jnp.squeeze(step, unit_axes)
 
 
+def _init_units(B: int, H: int, NB: int, n: int, dtype):
+    """Zeroed (min, step) for one tensor: one unit row per block, ``[B, H,
+    NB, 1, n]`` (n = D for K, T for V), so a block's units form a
+    whole-trailing-dims kernel tile."""
+    return tuple(jnp.zeros((B, H, NB, 1, n), dtype) for _ in range(2))
+
+
+def dequant_k(codes: Array, mn: Array, st: Array) -> Array:
+    """codes [..., T, D]; BlockQuant units mn/st [..., 1, D] -> f32."""
+    f32 = jnp.float32
+    return mn.astype(f32) + codes.astype(f32) * st.astype(f32)
+
+
+def dequant_v(codes: Array, mn: Array, st: Array) -> Array:
+    """codes [..., T, D]; TokenQuant units mn/st [..., 1, T] -> f32 (the
+    units are stored lane-dense and turned into a column here)."""
+    f32 = jnp.float32
+    col = lambda a: jnp.swapaxes(a.astype(f32), -1, -2)
+    return col(mn) + codes.astype(f32) * col(st)
+
+
 # ---------------------------------------------------------------------------
 # The layout interface + registry
 # ---------------------------------------------------------------------------
@@ -194,12 +215,15 @@ class FusedTileSpec:
     ``vmap``-ed over (B, H, NB) by the kernel's pure-jnp oracle, so one
     definition serves both paths.
 
-    k_tile / v_tile : per-block store tile shape (what one grid step loads),
-        e.g. ``(Wk,)`` packed words or ``(T, D)`` raw values.
+    k_tile / v_tile : per-block store tile shape (what one grid step loads):
+        always 2-D and equal to the store's two trailing dims, e.g.
+        ``(Rk, D)`` planar words or ``(T, D)`` raw values — the Mosaic
+        tiling rule every kernel operand obeys (DESIGN.md §9).
     has_scales      : whether (min, step) arrays accompany the store; when
         False the decode callables receive ``None`` for both.
     decode_k(tile, mn, st, *aux) -> [T, D] f32 ; decode_v likewise (mn/st
-        are the per-block BlockQuant/TokenQuant units).
+        are the per-block BlockQuant ``[1, D]`` / TokenQuant ``[1, T]``
+        units).
     aux             : per-LAYER operands (small numpy arrays, identical for
         every tile) the kernel stages into VMEM alongside the tiles and
         appends to each decode call — e.g. the huffman layout's flat
@@ -296,7 +320,7 @@ class CacheLayout:
     """
 
     name: str = "?"
-    # Eligible for the fused Pallas decode kernel (uniform no-straddle words).
+    # Eligible for the fused Pallas decode kernel (a Mosaic-legal tile_decode).
     supports_fused: bool = False
     # size_report needs a fitted huffman.CodeBook passed via ``book=``.
     needs_codebook: bool = False
@@ -498,7 +522,7 @@ class RawLayout(CacheLayout):
 
 
 # ---------------------------------------------------------------------------
-# packed: error-bounded quantizer + no-straddle bit-packing (the TPU path)
+# packed: error-bounded quantizer + planar no-straddle bit-packing (the TPU path)
 # ---------------------------------------------------------------------------
 
 
@@ -514,67 +538,54 @@ class PackedLayout(CacheLayout):
 
     def init_store(self, spec, batch, n_kv_heads, head_dim, dtype):
         B, H, T, D, NB = batch, n_kv_heads, spec.block_size, head_dim, spec.store_blocks
-        k_store = jnp.zeros((B, H, NB, spec.words_k(D)), jnp.uint32)
-        v_store = jnp.zeros((B, H, NB, spec.words_v(D)), jnp.uint32)
-        k_min = jnp.zeros((B, H, NB, D), dtype)
-        k_step = jnp.zeros((B, H, NB, D), dtype)
-        v_min = jnp.zeros((B, H, NB, T), dtype)
-        v_step = jnp.zeros((B, H, NB, T), dtype)
-        return k_store, k_min, k_step, v_store, v_min, v_step
+        k_store = jnp.zeros((B, H, NB, bitpack.planar_rows(T, spec.bits_k), D),
+                            jnp.uint32)
+        v_store = jnp.zeros((B, H, NB, bitpack.planar_rows(T, spec.bits_v), D),
+                            jnp.uint32)
+        return (k_store, *_init_units(B, H, NB, D, dtype),
+                v_store, *_init_units(B, H, NB, T, dtype))
 
     def quantize_blocks(self, spec, k: Array, v: Array):
         """Shared lossy stage for every quantizing layout: [B, H, NB, T, D]
         raw blocks -> (codes u8, min, step) per tensor.  K: BlockQuant —
         min/max over the block's T tokens, per channel; V: TokenQuant —
-        min/max over D, per token."""
+        min/max over D, per token.  Units come back in their stored
+        ``[..., 1, D]`` / ``[..., 1, T]`` shapes."""
         kf = k.astype(jnp.float32)
         vf = v.astype(jnp.float32)
         k_codes, k_mn, k_st = quant_block_minmax(
             kf, spec.rel_scale_k, spec.bits_k, (-2,), self.kivi_step)
         v_codes, v_mn, v_st = quant_block_minmax(
             vf, spec.rel_scale_v, spec.bits_v, (-1,), self.kivi_step)
-        return k_codes, k_mn, k_st, v_codes, v_mn, v_st
+        row = lambda a: a[..., None, :]
+        return k_codes, row(k_mn), row(k_st), v_codes, row(v_mn), row(v_st)
 
-    def compress_blocks(self, spec, k: Array, v: Array):
-        """Compress [B, H, NB, T, D] raw blocks -> packed stores + scales."""
-        k_codes, k_mn, k_st, v_codes, v_mn, v_st = self.quantize_blocks(spec, k, v)
-        B, H, NB, T, D = k.shape
-        k_store = bitpack.pack_nostraddle(k_codes.reshape(B, H, NB, T * D), spec.bits_k)
-        v_store = bitpack.pack_nostraddle(v_codes.reshape(B, H, NB, T * D), spec.bits_v)
-        dt = jnp.bfloat16
-        return (k_store, k_mn.astype(dt), k_st.astype(dt),
-                v_store, v_mn.astype(dt), v_st.astype(dt))
+    def encode_codes(self, spec, codes: Array, tensor: str) -> Array:
+        """Codes u8 ``[..., T, D]`` of K or V (``tensor``) -> stored slots."""
+        bits = spec.bits_k if tensor == "k" else spec.bits_v
+        return bitpack.pack_planar(codes, bits)
 
     def write_blocks(self, spec, cache, slots, kb, vb):
-        ks, kmn, kst, vs, vmn, vst = self.compress_blocks(spec, kb, vb)
-        return (
-            scatter_slots(cache.k_store, slots, ks),
-            scatter_slots(cache.k_min, slots, kmn),
-            scatter_slots(cache.k_step, slots, kst),
-            scatter_slots(cache.v_store, slots, vs),
-            scatter_slots(cache.v_min, slots, vmn),
-            scatter_slots(cache.v_step, slots, vst),
-        )
+        k_codes, k_mn, k_st, v_codes, v_mn, v_st = self.quantize_blocks(spec, kb, vb)
+        dt = jnp.bfloat16
+        new = (self.encode_codes(spec, k_codes, "k"), k_mn.astype(dt), k_st.astype(dt),
+               self.encode_codes(spec, v_codes, "v"), v_mn.astype(dt), v_st.astype(dt))
+        old = (cache.k_store, cache.k_min, cache.k_step,
+               cache.v_store, cache.v_min, cache.v_step)
+        return tuple(scatter_slots(a, slots, x) for a, x in zip(old, new))
+
+    def decode_codes(self, spec, store: Array, head_dim: int, tensor: str) -> Array:
+        """Stored block slots of K or V (``tensor``) -> codes ``[..., T, D]``."""
+        bits = spec.bits_k if tensor == "k" else spec.bits_v
+        return bitpack.unpack_planar(store, bits, spec.block_size)
 
     def decompress_k(self, spec, cache):
-        B, H, NB, _ = cache.k_store.shape
-        T, D = spec.block_size, cache.head_dim
-        codes = bitpack.unpack_nostraddle(
-            cache.k_store, spec.bits_k, T * D).reshape(B, H, NB, T, D)
-        return (cache.k_min[:, :, :, None, :].astype(jnp.float32)
-                + codes.astype(jnp.float32)
-                * cache.k_step[:, :, :, None, :].astype(jnp.float32)
-                ).astype(jnp.bfloat16)
+        codes = self.decode_codes(spec, cache.k_store, cache.head_dim, "k")
+        return dequant_k(codes, cache.k_min, cache.k_step).astype(jnp.bfloat16)
 
     def decompress_v(self, spec, cache):
-        B, H, NB, _ = cache.v_store.shape
-        T, D = spec.block_size, cache.head_dim
-        codes = bitpack.unpack_nostraddle(
-            cache.v_store, spec.bits_v, T * D).reshape(B, H, NB, T, D)
-        return (cache.v_min[:, :, :, :, None].astype(jnp.float32)
-                + codes.astype(jnp.float32)
-                * cache.v_step[:, :, :, :, None].astype(jnp.float32)
-                ).astype(jnp.bfloat16)
+        codes = self.decode_codes(spec, cache.v_store, cache.head_dim, "v")
+        return dequant_v(codes, cache.v_min, cache.v_step).astype(jnp.bfloat16)
 
     def decode_block(self, spec, cache, n):
         # Raw codes + scales: dequantization stays folded into the attention
@@ -583,41 +594,38 @@ class PackedLayout(CacheLayout):
         return tuple(a[:, :, 0] for a in out)
 
     def decode_span(self, spec, cache, start, count: int):
-        # One contiguous slice + one vectorized no-straddle unpack per tensor.
-        B, H = cache.k_store.shape[:2]
-        T, D = spec.block_size, cache.head_dim
+        # One contiguous slice + one vectorized decode per tensor; the units
+        # drop their stored unit axis for the scan's [B, H, C, D|T] contract.
         sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, count, 2)
-        kc = bitpack.unpack_nostraddle(sl(cache.k_store), spec.bits_k, T * D)
-        vc = bitpack.unpack_nostraddle(sl(cache.v_store), spec.bits_v, T * D)
-        return (kc.reshape(B, H, count, T, D).astype(jnp.float32),
-                sl(cache.k_min), sl(cache.k_step),
-                vc.reshape(B, H, count, T, D).astype(jnp.float32),
-                sl(cache.v_min), sl(cache.v_step))
+        D = cache.head_dim
+        kc = self.decode_codes(spec, sl(cache.k_store), D, "k")
+        vc = self.decode_codes(spec, sl(cache.v_store), D, "v")
+        unit = lambda a: sl(a)[..., 0, :]
+        return (kc.astype(jnp.float32), unit(cache.k_min), unit(cache.k_step),
+                vc.astype(jnp.float32), unit(cache.v_min), unit(cache.v_step))
 
     def _tile_decode(self, spec, head_dim):
         T, D = spec.block_size, head_dim
         bits_k, bits_v = spec.bits_k, spec.bits_v
 
-        def dk(tile, mn, st):
-            codes = bitpack.unpack_nostraddle_tile(
-                tile, bits_k, T * D).reshape(T, D).astype(jnp.float32)
-            return (mn.astype(jnp.float32)[None, :]
-                    + codes * st.astype(jnp.float32)[None, :])
+        def dk(tile, mn, st):  # tile [Rk, D] u32, units [1, D]
+            return dequant_k(bitpack.unpack_planar(tile, bits_k, T), mn, st)
 
-        def dv(tile, mn, st):
-            codes = bitpack.unpack_nostraddle_tile(
-                tile, bits_v, T * D).reshape(T, D).astype(jnp.float32)
-            return (mn.astype(jnp.float32)[:, None]
-                    + codes * st.astype(jnp.float32)[:, None])
+        def dv(tile, mn, st):  # tile [Rv, D] u32, units [1, T]
+            return dequant_v(bitpack.unpack_planar(tile, bits_v, T), mn, st)
 
-        return FusedTileSpec(k_tile=(spec.words_k(D),), v_tile=(spec.words_v(D),),
-                             has_scales=True, decode_k=dk, decode_v=dv)
+        return FusedTileSpec(
+            k_tile=(bitpack.planar_rows(T, bits_k), D),
+            v_tile=(bitpack.planar_rows(T, bits_v), D),
+            has_scales=True, decode_k=dk, decode_v=dv)
 
     def size_report(self, q, *, block_size, head_dim, kivi_bits=2, book=None):
         return packed_ratio(q, block_size * head_dim)
 
     def bytes_per_token(self, spec, n_kv_heads, head_dim):
-        payload = n_kv_heads * head_dim * (spec.bits_k + spec.bits_v) / 8
+        # Allocated planar words (no-straddle and row padding included).
+        payload = (n_kv_heads * 4.0 * (spec.words_k(head_dim) + spec.words_v(head_dim))
+                   / spec.block_size)
         # scales: K per (block, channel) 2x bf16; V per token 2x bf16
         meta = n_kv_heads * (2 * head_dim * 2 * 2 / spec.block_size + 2 * 2)
         return payload + meta
@@ -695,18 +703,27 @@ class HuffmanLayout(PackedLayout):
     accounts the *actual* entropy-coded bits (DESIGN.md §4).
 
     The payload is ragged INSIDE the slot, but the slot itself is a fixed
-    worst-case-padded tile — so the fused Pallas kernel streams whole slots
-    HBM→VMEM like any other layout and ``tile_decode`` re-derives the
-    per-stream offsets from the header in VMEM (``supports_fused``).  Both
-    the in-kernel decode and the blockwise XLA floor run the chunked
+    worst-case-padded ``[1, W]`` tile — so the fused Pallas kernel streams
+    whole slots HBM→VMEM like any other layout and ``tile_decode``
+    re-derives the per-stream offsets from the header in VMEM.  Both the
+    in-kernel decode and the blockwise XLA floor run the chunked
     direct-lookup decoder (``huffman.decode_block_lut_jax``): ≤ 2 LUT
     probes per symbol instead of one tree step per bit, with the canonical
     codebooks' flat LUTs riding along as the tile spec's per-layer ``aux``
-    operands (DESIGN.md §9).
+    operands (DESIGN.md §9).  That decoder gathers per lane from the slot
+    and the LUT, which Mosaic does not lower, so on a TPU the layout is not
+    fused-capable and ``auto`` serves it through the blockwise scan.
     """
 
-    supports_fused = True  # fixed-size slot tiles; offsets decoded in VMEM
     needs_codebook = True
+
+    @property
+    def supports_fused(self) -> bool:
+        # The one place the TPU/huffman decision lives (DESIGN.md §9): the
+        # in-kernel LUT decode runs in interpret mode off-TPU only.
+        from repro.kernels.runtime import on_tpu  # late: kernels import core
+
+        return not on_tpu()
 
     # -- codebooks (static prior; see default_codebook) ----------------------
     def _n_codes(self, spec, bits: int, rel_scale: float) -> int:
@@ -729,18 +746,16 @@ class HuffmanLayout(PackedLayout):
 
     def init_store(self, spec, batch, n_kv_heads, head_dim, dtype):
         B, H, T, D, NB = batch, n_kv_heads, spec.block_size, head_dim, spec.store_blocks
-        hk, pk = self._slot_words(spec, D, self.book_k(spec))
-        hv, pv = self._slot_words(spec, D, self.book_v(spec))
-        k_store = jnp.zeros((B, H, NB, hk + pk), jnp.uint32)
-        v_store = jnp.zeros((B, H, NB, hv + pv), jnp.uint32)
-        k_min = jnp.zeros((B, H, NB, D), dtype)
-        k_step = jnp.zeros((B, H, NB, D), dtype)
-        v_min = jnp.zeros((B, H, NB, T), dtype)
-        v_step = jnp.zeros((B, H, NB, T), dtype)
-        return k_store, k_min, k_step, v_store, v_min, v_step
+        wk = sum(self._slot_words(spec, D, self.book_k(spec)))
+        wv = sum(self._slot_words(spec, D, self.book_v(spec)))
+        k_store = jnp.zeros((B, H, NB, 1, wk), jnp.uint32)
+        v_store = jnp.zeros((B, H, NB, 1, wv), jnp.uint32)
+        return (k_store, *_init_units(B, H, NB, D, dtype),
+                v_store, *_init_units(B, H, NB, T, dtype))
 
-    def _encode(self, spec, codes: Array, book: huffman.CodeBook) -> Array:
-        """codes u8 [B, H, n, T, D] -> slots u32 [B, H, n, hdr+payload]."""
+    def encode_codes(self, spec, codes: Array, tensor: str) -> Array:
+        """codes u8 [B, H, n, T, D] -> slots u32 [B, H, n, 1, hdr+payload]."""
+        book = self.book_k(spec) if tensor == "k" else self.book_v(spec)
         B, H, n, T, D = codes.shape
         hdr_w, pay_w = self._slot_words(spec, D, book)
         cl, ln = book.as_encode_tables()
@@ -750,16 +765,17 @@ class HuffmanLayout(PackedLayout):
             return jnp.concatenate([_pack_u16_pairs(nbits), payload])
 
         slots = jax.vmap(enc)(codes.reshape(B * H * n, T, D))
-        return slots.reshape(B, H, n, hdr_w + pay_w)
+        return slots.reshape(B, H, n, 1, hdr_w + pay_w)
 
-    def _decode(self, spec, store: Array, head_dim: int, book: huffman.CodeBook) -> Array:
-        """slots u32 [B, H, NB, W] -> codes u8 [B, H, NB, T, D].
+    def decode_codes(self, spec, store: Array, head_dim: int, tensor: str) -> Array:
+        """slots u32 [..., 1, W] -> codes u8 [..., T, D].
 
         Chunked LUT decode (≤ 2 probes per symbol) — the same decoder the
         fused kernel runs per tile, here vmapped over every slot for the
         blockwise XLA floor.
         """
-        B, H, NB, _ = store.shape
+        book = self.book_k(spec) if tensor == "k" else self.book_v(spec)
+        lead, W = store.shape[:-2], store.shape[-1]
         T, D = spec.block_size, head_dim
         hdr_w, _ = self._slot_words(spec, D, book)
         lut = jnp.asarray(book.decode_lut())
@@ -770,54 +786,8 @@ class HuffmanLayout(PackedLayout):
             return huffman.decode_block_lut_jax(slot[hdr_w:], nbits, lut, D, probes)
 
         with annotate("huffman_lut_decode"):
-            codes = jax.vmap(dec)(store.reshape(B * H * NB, -1))
-        return codes.reshape(B, H, NB, T, D)
-
-    def write_blocks(self, spec, cache, slots, kb, vb):
-        k_codes, k_mn, k_st, v_codes, v_mn, v_st = self.quantize_blocks(spec, kb, vb)
-        ks = self._encode(spec, k_codes, self.book_k(spec))
-        vs = self._encode(spec, v_codes, self.book_v(spec))
-        dt = jnp.bfloat16
-        return (
-            scatter_slots(cache.k_store, slots, ks),
-            scatter_slots(cache.k_min, slots, k_mn.astype(dt)),
-            scatter_slots(cache.k_step, slots, k_st.astype(dt)),
-            scatter_slots(cache.v_store, slots, vs),
-            scatter_slots(cache.v_min, slots, v_mn.astype(dt)),
-            scatter_slots(cache.v_step, slots, v_st.astype(dt)),
-        )
-
-    def decompress_k(self, spec, cache):
-        codes = self._decode(spec, cache.k_store, cache.head_dim, self.book_k(spec))
-        return (cache.k_min[:, :, :, None, :].astype(jnp.float32)
-                + codes.astype(jnp.float32)
-                * cache.k_step[:, :, :, None, :].astype(jnp.float32)
-                ).astype(jnp.bfloat16)
-
-    def decompress_v(self, spec, cache):
-        codes = self._decode(spec, cache.v_store, cache.head_dim, self.book_v(spec))
-        return (cache.v_min[:, :, :, :, None].astype(jnp.float32)
-                + codes.astype(jnp.float32)
-                * cache.v_step[:, :, :, :, None].astype(jnp.float32)
-                ).astype(jnp.bfloat16)
-
-    def decode_block(self, spec, cache, n):
-        out = self.decode_span(spec, cache, n, 1)
-        return tuple(a[:, :, 0] for a in out)
-
-    def decode_span(self, spec, cache, start, count: int):
-        # LUT decode of one SPAN of blocks per scan step (the vmapped
-        # decoder batches over B·H·count slots) — the blockwise path never
-        # reconstructs the whole [B, H, NB, T, D] store.  Codes are
-        # bit-identical to the packed layout's, so the downstream fused
-        # matvec algebra is shared unchanged.
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, count, 2)
-        kc = self._decode(spec, sl(cache.k_store), cache.head_dim,
-                          self.book_k(spec))
-        vc = self._decode(spec, sl(cache.v_store), cache.head_dim,
-                          self.book_v(spec))
-        return (kc.astype(jnp.float32), sl(cache.k_min), sl(cache.k_step),
-                vc.astype(jnp.float32), sl(cache.v_min), sl(cache.v_step))
+            codes = jax.vmap(dec)(store.reshape(-1, W))
+        return codes.reshape(*lead, T, D)
 
     def _tile_decode(self, spec, head_dim):
         # One tile = one whole worst-case-padded slot (header ∥ payload);
@@ -829,21 +799,20 @@ class HuffmanLayout(PackedLayout):
         hk, pk = self._slot_words(spec, D, book_k)
         hv, pv = self._slot_words(spec, D, book_v)
         probes_k, probes_v = book_k.decode_probes, book_v.decode_probes
-        f32 = jnp.float32
 
-        def dk(tile, mn, st, lut_k, lut_v):
-            nbits = _unpack_u16_pairs(tile[:hk], T)
+        def dk(tile, mn, st, lut_k, lut_v):  # tile [1, W]
+            nbits = _unpack_u16_pairs(tile[0, :hk], T)
             codes = huffman.decode_block_lut_jax(
-                tile[hk:], nbits, lut_k, D, probes_k).astype(f32)  # [T, D]
-            return mn.astype(f32)[None, :] + codes * st.astype(f32)[None, :]
+                tile[0, hk:], nbits, lut_k, D, probes_k)  # [T, D]
+            return dequant_k(codes, mn, st)
 
         def dv(tile, mn, st, lut_k, lut_v):
-            nbits = _unpack_u16_pairs(tile[:hv], T)
+            nbits = _unpack_u16_pairs(tile[0, :hv], T)
             codes = huffman.decode_block_lut_jax(
-                tile[hv:], nbits, lut_v, D, probes_v).astype(f32)
-            return mn.astype(f32)[:, None] + codes * st.astype(f32)[:, None]
+                tile[0, hv:], nbits, lut_v, D, probes_v)
+            return dequant_v(codes, mn, st)
 
-        return FusedTileSpec(k_tile=(hk + pk,), v_tile=(hv + pv,),
+        return FusedTileSpec(k_tile=(1, hk + pk), v_tile=(1, hv + pv),
                              has_scales=True, decode_k=dk, decode_v=dv,
                              aux=(book_k.decode_lut(), book_v.decode_lut()))
 

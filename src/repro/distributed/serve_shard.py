@@ -48,10 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 re-exports at top level; the pinned 0.4.37 does not
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import cache as kvcache
 from repro.core.pool import STORE_FIELDS, PagedBlockPool
@@ -362,7 +359,7 @@ def _sharded_backend(cache, q: Array, scale: float | None = None) -> Array:
         body, mesh=mesh,
         in_specs=(cache_partition_specs(cache, mesh), P("data", "model", None)),
         out_specs=P("data", "model", None),
-        check_rep=False)
+        check_vma=False)
     o = fn(cache, q)
     # Pure all-gather of the head axis before o_proj: replicated weights +
     # per-head-exact attention keep greedy outputs bit-identical.

@@ -27,36 +27,18 @@ from repro.models.config import ModelConfig
 MeshAxes = tuple[str, ...] | str | None
 
 
-# ---------------------------------------------------------------------------
-# jax version compatibility (pinned jax 0.4.37 predates jax.sharding.AxisType
-# and the explicit-axis make_mesh/AbstractMesh signatures)
-# ---------------------------------------------------------------------------
-
-#: True when this jax exposes the explicit/auto axis-type API (jax >= 0.5).
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """``jax.make_mesh`` with every axis pinned Auto where the API exists,
-    and a guarded fallback for older jax (0.4.x has no ``axis_types``)."""
+    """``jax.make_mesh`` with every axis pinned Auto (the sharding rules
+    below rely on the automatic partitioner)."""
     kwargs = {} if devices is None else {"devices": devices}
-    if HAS_AXIS_TYPES:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-                **kwargs)
-        except TypeError:  # AxisType present but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs)
 
 
 def abstract_mesh(axis_shapes, axis_names) -> jax.sharding.AbstractMesh:
-    """AbstractMesh across the signature change: (sizes, names) on jax >= 0.5
-    vs a single ((name, size), ...) tuple on 0.4.x."""
-    if HAS_AXIS_TYPES:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    """Device-free mesh of the given (sizes, names) for shape-only rules."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
 
 def _mesh_size(mesh, name: str) -> int:
@@ -165,9 +147,8 @@ def spec_for_axes(axes: tuple[str, ...], shape: tuple[int, ...],
             size *= n
         for a in kept:
             used.add(a)
-        # Singleton axes unwrap to the bare name: PartitionSpec("x") and
-        # PartitionSpec(("x",)) mean the same sharding, but only compare
-        # equal on newer jax — normalize for the pinned 0.4.37.
+        # Singleton axes unwrap to the bare name: PartitionSpec("x") is the
+        # canonical spelling of PartitionSpec(("x",)).
         entries.append(kept[0] if len(kept) == 1 else tuple(kept) if kept else None)
     while entries and entries[-1] is None:
         entries.pop()

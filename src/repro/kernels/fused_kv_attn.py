@@ -10,13 +10,13 @@ playing the role of GPU shared memory and the MXU taking the dot products.
 The per-tile decode is NOT hardcoded to one layout: the kernel is
 parameterized by a ``repro.core.layouts.FusedTileSpec`` — the layout-owned
 ``tile_decode`` hook (DESIGN.md §9).  ``packed``/``kivi`` share the
-no-straddle shift/mask unpack; ``raw`` plugs in a passthrough decoder; and
-``huffman`` decodes its ragged-payload slots via the tile spec's per-layer
+planar no-straddle shift/mask unpack; ``raw`` plugs in a passthrough
+decoder; and ``huffman`` (off-TPU only: Mosaic does not lower its LUT
+gathers) decodes its ragged-payload slots via the tile spec's per-layer
 ``aux`` operands — block-invariant arrays (the canonical codebooks' chunked
 direct-lookup LUTs) the kernel stages into VMEM with constant index maps
 and appends to every decode call, while the per-stream u16 bit counts
-arrive as part of the fixed worst-case-padded slot tile itself.  So the
-kernel is the uniform decode path rather than a packed-only special case.
+arrive as part of the fixed worst-case-padded slot tile itself.
 
 Grid: ``(B, Hkv, NB + 1)``.  TPU grids execute sequentially with the last
 axis innermost, so VMEM scratch carries the flash-decoding running state
@@ -33,9 +33,12 @@ scalar-prefetch operand: the store BlockSpec index maps resolve logical
 block ``n`` of row ``b`` to its physical arena page before the tile streams
 HBM→VMEM, so the kernel body decodes pooled storage completely unchanged.
 
-Block shapes keep the MXU happy when ``D`` and ``block_size`` are multiples
-of 128/8; odd head_dims (80, 112, 160 in the assigned archs) run via the
-interpreter off-TPU and rely on Mosaic relayout on real hardware.
+Tiling rule (DESIGN.md §9): every operand's block spans whole trailing
+dims — ``q``/``out`` as ``[B, Hkv, G, D]`` (block ``(1, 1, G, D)``), store
+tiles as the layout's 2-D ``k_tile``/``v_tile``, the quantization units as
+``(1, D)`` / ``(1, T)`` rows — so Mosaic never sees a block dim that is
+neither a multiple of (8, 128) nor the full array dim, whatever G, D, T or
+the packed word count are.
 """
 
 from __future__ import annotations
@@ -122,7 +125,7 @@ def _kernel(
                       kst_ref[0, 0, 0] if has_scales else None,
                       *aux)  # [T, D]
         # --- scores on the MXU ---
-        qg = q_ref[0].astype(jnp.float32)  # [G, D]
+        qg = q_ref[0, 0].astype(jnp.float32)  # [G, D]
         s = jax.lax.dot_general(qg, kd, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         # --- flash-decoding running softmax ---
@@ -144,7 +147,7 @@ def _kernel(
     # (masked per row by buf_len) and emit the normalized output.
     @pl.when(n == nb_total)
     def _buffer_and_emit():
-        qg = q_ref[0].astype(jnp.float32)  # [G, D]
+        qg = q_ref[0, 0].astype(jnp.float32)  # [G, D]
         kb = kbuf_ref[0, 0].astype(jnp.float32)  # [T, D]
         s = jax.lax.dot_general(qg, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -159,7 +162,7 @@ def _kernel(
         acc = acc_s[...] * alpha[:, None] + jax.lax.dot(
             p, vb, preferred_element_type=jnp.float32)
         l = l_s[...] * alpha + jnp.sum(p, axis=1)
-        out_ref[0] = acc / jnp.maximum(l, 1e-30)[:, None]
+        out_ref[0, 0] = acc / jnp.maximum(l, 1e-30)[:, None]
 
 
 def fused_cache_attention_pallas(
@@ -216,10 +219,14 @@ def fused_cache_attention_pallas(
         return lambda b, h, n, *scalars: tuple(
             b if i == "b" else h if i == "h" else i for i in idx)
 
-    in_specs.append(pl.BlockSpec((1, G, D), fixed_map("b", "h", 0)))
-    inputs.append(q)
+    in_specs.append(pl.BlockSpec((1, 1, G, D), fixed_map("b", "h", 0, 0)))
+    inputs.append(q.reshape(B, Hkv, G, D))
 
     def add_store(arr, tile_shape):
+        if arr.shape[3:] != tuple(tile_shape):
+            raise ValueError(
+                f"store operand {arr.shape} does not end in its tile "
+                f"{tuple(tile_shape)}: a block must span whole trailing dims")
         r = len(tile_shape)
         if paged:
             def imap(b, h, n, nb, bl, pt, r=r):
@@ -233,12 +240,12 @@ def fused_cache_attention_pallas(
 
     add_store(k_store, tile.k_tile)
     if tile.has_scales:
-        add_store(k_min, (D,))
-        add_store(k_step, (D,))
+        add_store(k_min, (1, D))
+        add_store(k_step, (1, D))
     add_store(v_store, tile.v_tile)
     if tile.has_scales:
-        add_store(v_min, (T,))
-        add_store(v_step, (T,))
+        add_store(v_min, (1, T))
+        add_store(v_step, (1, T))
     for buf in (k_buf, v_buf):
         in_specs.append(pl.BlockSpec((1, 1, T, D), fixed_map("b", "h", 0, 0)))
         inputs.append(buf)
@@ -249,7 +256,7 @@ def fused_cache_attention_pallas(
         in_specs.append(pl.BlockSpec(arr.shape, fixed_map(*(0,) * arr.ndim)))
         inputs.append(arr)
 
-    out_spec = pl.BlockSpec((1, G, D), fixed_map("b", "h", 0))
+    out_spec = pl.BlockSpec((1, 1, G, D), fixed_map("b", "h", 0, 0))
     scalars = [
         jnp.broadcast_to(jnp.atleast_1d(nb_valid), (B,)).astype(jnp.int32),
         jnp.broadcast_to(jnp.atleast_1d(buf_len), (B,)).astype(jnp.int32),
@@ -269,6 +276,6 @@ def fused_cache_attention_pallas(
                 pltpu.VMEM((G,), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(*scalars, *inputs)
+    )(*scalars, *inputs).reshape(B, Hq, D)

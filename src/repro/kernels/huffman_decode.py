@@ -16,8 +16,8 @@ dependent control flow anywhere in the loop, exactly as in the paper.
 DESIGN.md §2 records the hardware caveat: the per-lane gathers
 (``children[idx, bit]``, the masked output scatter, and ``q[w]`` in the fused
 variant) vectorize in interpret mode but are VPU-hostile on real TPU hardware;
-the production bandwidth path is ``fused_kv_attn`` over the no-straddle
-layout.  This kernel exists to validate the algorithm end-to-end and to
+the production bandwidth path is ``fused_kv_attn`` over the planar
+no-straddle layout.  This kernel exists to validate the algorithm end-to-end and to
 measure the faithful single-kernel-vs-multi-kernel comparison (paper Fig. 9).
 
 Layout: one grid step decodes one 2D block — ``S`` streams (rows of

@@ -76,20 +76,25 @@ def resolve_backend(backend: str | None, layout) -> str:
     selection — explicit requests win, so the CI matrix steers every
     default-configured path without defeating tests that pin a backend.
     ``auto`` resolves to fused on real TPU for fused-capable layouts and to
-    the blockwise scan elsewhere; a fused request against a layout without
-    ``supports_fused`` (every built-in layout is fused-capable now that
-    huffman decodes in-kernel, but custom layouts need not be) falls back
-    to the blockwise scan — the portable floor every layout can serve from.
+    the blockwise scan elsewhere; steered to ``fused``, it still serves a
+    layout the kernel cannot take through the scan.  An explicit ``fused``
+    request against a layout without ``supports_fused`` (huffman on a TPU,
+    custom layouts without a tile decoder) raises rather than quietly
+    serving another path.
     """
     from repro.kernels.runtime import on_tpu
 
     name = backend or "auto"
     if name == "auto":
         name = os.environ.get(ENV_BACKEND) or "auto"
+        if name in ("auto", "fused") and not layout.supports_fused:
+            name = "xla"
     if name == "auto":
-        name = "fused" if (on_tpu() and layout.supports_fused) else "xla"
+        name = "fused" if on_tpu() else "xla"
     if name == "fused" and not layout.supports_fused:
-        name = "xla"
+        raise ValueError(
+            f"attention backend 'fused' cannot serve layout {layout.name!r} "
+            f"here (supports_fused is False); request 'auto' or 'xla'")
     if name not in _BACKENDS:
         raise ValueError(
             f"unknown attention backend {name!r}; available: {available_backends()}")

@@ -20,22 +20,24 @@ NEG_INIT = -1e30  # finite "-inf" so flash combines never produce NaN
 
 
 def dequant_k(codes: Array, k_min: Array, k_step: Array) -> Array:
-    """codes [..., T, D], k_min/k_step [..., D] (BlockQuant units)."""
-    return k_min[..., None, :].astype(jnp.float32) + codes.astype(jnp.float32) * k_step[..., None, :].astype(jnp.float32)
+    """codes [..., T, D], k_min/k_step [..., 1, D] (BlockQuant units)."""
+    return (k_min.astype(jnp.float32)
+            + codes.astype(jnp.float32) * k_step.astype(jnp.float32))
 
 
 def dequant_v(codes: Array, v_min: Array, v_step: Array) -> Array:
-    """codes [..., T, D], v_min/v_step [..., T] (TokenQuant units)."""
-    return v_min[..., None].astype(jnp.float32) + codes.astype(jnp.float32) * v_step[..., None].astype(jnp.float32)
+    """codes [..., T, D], v_min/v_step [..., 1, T] (TokenQuant units)."""
+    return (v_min[..., 0, :, None].astype(jnp.float32)
+            + codes.astype(jnp.float32) * v_step[..., 0, :, None].astype(jnp.float32))
 
 
 def fused_cache_attention_ref(
     q: Array,          # [B, Hq, D]
     k_store: Array,    # [B, Hkv, NB, *tile.k_tile]  (paged: [1, Hkv, P, ...])
-    k_min: Array,      # [B, Hkv, NB, D] (ignored when not tile.has_scales)
+    k_min: Array,      # [B, Hkv, NB, 1, D] (ignored when not tile.has_scales)
     k_step: Array,
     v_store: Array,    # [B, Hkv, NB, *tile.v_tile]
-    v_min: Array,      # [B, Hkv, NB, T]
+    v_min: Array,      # [B, Hkv, NB, 1, T]
     v_step: Array,
     k_buf: Array, v_buf: Array,  # [B, Hkv, T, D]
     nb_valid: Array,   # i32 [B] per-row valid block counts (scalar broadcasts)
@@ -142,11 +144,13 @@ def combine_with_buffer_ref(
 
 
 def quant_pack_ref(x: Array, rel_scale: float, bits: int, token_wise: bool):
-    """Oracle for the Store-stage kernel: quantize + no-straddle pack.
+    """Oracle for the Store-stage kernel: quantize + planar no-straddle pack.
 
     x: [NBLK, T, D].  token_wise=False -> K BlockQuant (units: block×channel);
     True -> V TokenQuant (units: token).
-    Returns (words u32 [NBLK, W], mn, step).
+    Returns (words u32 [NBLK, R, D] planar, mn [NBLK, 1, U], step
+    [NBLK, 1, U]) with U = T for token_wise else D — the cache layouts'
+    stored shapes.
     """
     xf = x.astype(jnp.float32)
     axes = (-1,) if token_wise else (-2,)
@@ -155,9 +159,9 @@ def quant_pack_ref(x: Array, rel_scale: float, bits: int, token_wise: bool):
     step = rel_scale * (mx - mn)
     safe = jnp.where(step > 0, step, 1.0)
     codes = jnp.clip(jnp.round((xf - mn) / safe), 0, 2**bits - 1).astype(jnp.uint8)
-    NBLK, T, D = x.shape
-    words = bitpack.pack_nostraddle(codes.reshape(NBLK, T * D), bits)
-    return words, jnp.squeeze(mn, axes), jnp.squeeze(step, axes)
+    words = bitpack.pack_planar(codes, bits)
+    row = lambda a: jnp.squeeze(a, axes)[:, None, :]
+    return words, row(mn), row(step)
 
 
 def huffman_decode_ref(payload, nbits, children, is_symbol, symbols, n_per_stream, max_stream_bits):

@@ -224,10 +224,6 @@ def parse_collective_bytes(hlo: str) -> dict:
 
 def cost_numbers(compiled) -> dict:
     ca = compiled.cost_analysis()
-    # jax < 0.5 returns a one-dict-per-device LIST from some executables
-    # (donated-argument decode steps among them); normalize to the dict.
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -5,7 +5,7 @@ importing this module never touches jax device state — required because the
 dry-run must set XLA_FLAGS before any jax initialization.
 
 Mesh creation goes through ``repro.distributed.sharding.make_mesh``, which
-hides the jax-version split around ``jax.sharding.AxisType``.
+pins every axis to the automatic partitioner.
 """
 
 from __future__ import annotations

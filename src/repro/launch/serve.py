@@ -3,32 +3,102 @@ cache.
 
     python -m repro.launch.serve --arch yi_6b --layout packed --requests 8
     python -m repro.launch.serve --arch yi_6b --layout raw   # baseline
+    python -m repro.launch.serve --size published --max-seq 2048   # on a TPU
 
 Requests get heterogeneous prompt lengths and token budgets and are pushed
 through the ``api.serve`` Server — slots admit, decode at per-row positions,
-retire, and are reused mid-flight.  Prints per-request results plus the
-per-layout cache memory, so the paper's memory-reduction and overhead story
-is visible end to end on CPU.
+retire, and are reused mid-flight.  Prints the device, per-request results
+and the per-layout cache memory.  ``--size smoke`` (the default, sized for
+the CPU) serves the architecture's reduced config; ``--size published``
+serves its published widths with only the depth cut (to
+``PUBLISHED_LAYERS``), with random bf16 weights.  Exits 1 when any request ends in ``error``.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 import jax
 import numpy as np
 
 from repro import api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serve_mesh
+from repro.models import layers as L
 from repro.models import model as M
 from repro.models import registry
 
+# Published-width depth: yi_6b's bf16 weights are ~1.05 GB of embeddings
+# plus ~0.35 GB per layer, so 8 layers (~3.8 GB) leave a 16 GB chip ample
+# room for a 4-slot 2k-token cache and the compiled step.
+PUBLISHED_LAYERS = 8
 
-def main():
+
+def model_config(arch: str, size: str = "smoke"):
+    """The served ModelConfig: the arch's smoke config, or its published
+    config at full width with only ``n_layers`` cut to ``PUBLISHED_LAYERS``."""
+    if size == "smoke":
+        return registry.get_smoke_config(arch)
+    cfg = registry.get_config(arch)
+    return dataclasses.replace(cfg, n_layers=min(PUBLISHED_LAYERS, cfg.n_layers))
+
+
+def describe_cut(arch: str, cfg) -> str:
+    """One line naming the served model and how it was cut."""
+    full = registry.get_config(arch)
+    widths = (f"d_model {cfg.d_model}, heads {cfg.n_heads}/{cfg.n_kv_heads}, "
+              f"head_dim {cfg.resolved_head_dim}, d_ff {cfg.d_ff}, "
+              f"vocab {cfg.vocab_size}, cache_block {cfg.cache_block}")
+    if cfg.d_model != full.d_model:
+        return f"model {cfg.name}: smoke config ({widths}, {cfg.n_layers} layers)"
+    return (f"model {cfg.name}: published widths ({widths}); depth cut "
+            f"{full.n_layers} -> {cfg.n_layers} layers; random {cfg.dtype} weights")
+
+
+def init_params(cfg, seed: int = 0):
+    """Random weights in the config's own dtype (bf16 for published
+    configs), from a fixed seed, generated on the device in one program."""
+    init = jax.jit(lambda key: M.init_params(cfg, key, L.dtype_of(cfg.dtype))[0])
+    return init(jax.random.PRNGKey(seed))
+
+
+def device_info() -> dict:
+    """The device a run used, as JAX reports it."""
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def workload(cfg, n_requests: int, prompt_len: int, new_tokens: int,
+             shared_prefix: bool = False, seed: int = 0) -> list:
+    """Heterogeneous requests: prompts from half to full ``prompt_len``,
+    budgets from half to full ``new_tokens``; with ``shared_prefix`` they
+    share a system prompt of half ``prompt_len``."""
+    rng = np.random.default_rng(seed)
+    shared = (rng.integers(0, cfg.vocab_size, prompt_len // 2).astype(np.int32)
+              if shared_prefix else np.zeros(0, np.int32))
+    span = max(n_requests - 1, 1)
+    reqs = []
+    for i in range(n_requests):
+        plen = max(4, prompt_len - (i * prompt_len // 2) // span)
+        n_new = max(2, new_tokens - (i * new_tokens // 2) // span)
+        tail = rng.integers(0, cfg.vocab_size,
+                            max(plen - len(shared), 1)).astype(np.int32)
+        reqs.append(api.Request(prompt=np.concatenate([shared, tail]),
+                                max_new_tokens=n_new))
+    return reqs
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi_6b")
+    ap.add_argument("--size", default="smoke", choices=["smoke", "published"],
+                    help="smoke: the arch's reduced CPU-sized config; "
+                         f"published: its published widths, depth cut to "
+                         f"{PUBLISHED_LAYERS} layers, random bf16 weights")
     ap.add_argument("--layout", default="packed",
                     choices=list(api.available_layouts()))
     ap.add_argument("--backend", default=None,
@@ -114,15 +184,18 @@ def main():
                          "prefix_insert, chunk_prefill, decode_dispatch")
     ap.add_argument("--fault-seed", type=int, default=0,
                     help="seed for the --fault schedule (replayable)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = registry.get_smoke_config(args.arch)
+    enable_compile_cache()
+    cfg = model_config(args.arch, args.size)
     cfg = dataclasses.replace(cfg, cache_layout=args.layout)
     if args.span_tokens is not None:
         cfg = dataclasses.replace(cfg, cache_span_tokens=args.span_tokens)
     if args.unroll_max is not None:
         cfg = dataclasses.replace(cfg, cache_unroll_max=args.unroll_max)
-    params, _ = M.init_params(cfg, jax.random.PRNGKey(0))
+    print(f"device={device_info()}")
+    print(describe_cut(args.arch, cfg))
+    params = init_params(cfg)
     mesh = make_serve_mesh(args.mesh) if args.mesh else None
     faults = None
     if args.fault:
@@ -146,23 +219,11 @@ def main():
                        default_deadline_s=args.deadline,
                        faults=faults,
                        audit_every=args.audit_every)
-    rng = np.random.default_rng(0)
     # With the prefix cache enabled, requests share a system-prompt prefix
     # (half of --prompt-len) so the printed hit-rate exercises real reuse.
-    shared = (rng.integers(0, cfg.vocab_size, args.prompt_len // 2)
-              .astype(np.int32) if args.prefix_cache != "off" else
-              np.zeros(0, np.int32))
-    handles = []
-    for i in range(args.requests):
-        # heterogeneous workload: prompts from half to full --prompt-len,
-        # budgets from half to full --new-tokens
-        plen = max(4, args.prompt_len - (i * args.prompt_len // 2) // max(args.requests - 1, 1))
-        n_new = max(2, args.new_tokens - (i * args.new_tokens // 2) // max(args.requests - 1, 1))
-        tail = rng.integers(0, cfg.vocab_size,
-                            max(plen - len(shared), 1)).astype(np.int32)
-        prompt = np.concatenate([shared, tail])
-        handles.append(server.submit(api.Request(prompt=prompt,
-                                                 max_new_tokens=n_new)))
+    handles = [server.submit(r) for r in workload(
+        cfg, args.requests, args.prompt_len, args.new_tokens,
+        shared_prefix=args.prefix_cache != "off")]
     t0 = time.monotonic()
     try:
         server.run()
@@ -200,6 +261,11 @@ def main():
               f"ttft={ttft} "
               f"prefill={r.prefill_s * 1e3:.0f}ms gen={r.gen_s * 1e3:.0f}ms "
               f"finish={r.finish_reason} tokens={r.tokens[:8].tolist()}…")
+    failed = [i for i, r in enumerate(results) if r.finish_reason == "error"]
+    if failed:
+        for i in failed:
+            print(f"req{i} failed: {results[i].error}", file=sys.stderr)
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

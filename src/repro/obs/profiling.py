@@ -12,9 +12,6 @@ is the *runtime* counterpart (``jax.profiler.TraceAnnotation``) for host
 regions, and ``trace_capture(dir)`` brackets a block with
 ``jax.profiler.start_trace``/``stop_trace`` — the hook behind
 ``benchmarks/serve_throughput.py --profile-dir``.
-
-Every entry degrades to a no-op when the running jax build lacks the
-profiler pieces, so annotated library code never gains a hard dependency.
 """
 
 from __future__ import annotations
@@ -39,11 +36,7 @@ def annotate(name: str):
 def annotation(name: str):
     """Runtime (host-side) profiler annotation around a region — shows up
     as a track slice in a captured ``jax.profiler`` trace."""
-    ta = getattr(jax.profiler, "TraceAnnotation", None)
-    if ta is None:  # profiler build without annotations: free no-op
-        yield
-        return
-    with ta(f"{SCOPE_PREFIX}:{name}"):
+    with jax.profiler.TraceAnnotation(f"{SCOPE_PREFIX}:{name}"):
         yield
 
 
@@ -51,22 +44,13 @@ def annotation(name: str):
 def trace_capture(log_dir: str | None):
     """Capture a ``jax.profiler`` device+host trace into ``log_dir`` for
     the duration of the block; ``None`` disables (the default path costs
-    nothing).  Capture failures degrade to a warning-free no-op — CI boxes
-    without profiler support must not fail the benchmark around it."""
+    nothing).  A trace that cannot start or stop raises: a run asked to
+    produce a trace must not exit 0 without one."""
     if not log_dir:
         yield
         return
-    started = False
-    try:
-        jax.profiler.start_trace(str(log_dir))
-        started = True
-    except Exception:  # noqa: BLE001 — profiling is best-effort by design
-        pass
+    jax.profiler.start_trace(str(log_dir))
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001
-                pass
+        jax.profiler.stop_trace()

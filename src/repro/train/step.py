@@ -160,13 +160,7 @@ def _cross_pod_compressed_allreduce(grads, err, mesh: Mesh, pshard):
     """Error-feedback int8 compression on the pod axis (shard_map, other axes
     auto).  Gradients arrive already reduced over in-pod data axes by the
     SPMD partitioner; only the pod-axis reduction is intercepted here."""
-    try:  # jax >= 0.6 top-level API
-        from jax import shard_map
-        sm_kwargs = dict(axis_names={"pod"}, check_vma=False)
-    except ImportError:  # pinned 0.4.x: experimental home + auto/check_rep
-        from jax.experimental.shard_map import shard_map
-        sm_kwargs = dict(auto=frozenset(a for a in mesh.axis_names if a != "pod"),
-                         check_rep=False)
+    from jax import shard_map
 
     def per_pod(g_tree, e_tree):
         gq, e_new = grad_compress.tree_compress_decompress(g_tree, e_tree)
@@ -181,7 +175,7 @@ def _cross_pod_compressed_allreduce(grads, err, mesh: Mesh, pshard):
     fn = shard_map(
         per_pod, mesh=mesh,
         in_specs=(specs_g, specs_g), out_specs=(specs_g, specs_g),
-        **sm_kwargs)
+        axis_names={"pod"}, check_vma=False)
     return fn(grads, err)
 
 

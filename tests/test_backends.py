@@ -153,8 +153,9 @@ def test_resolve_backend_auto_off_tpu():
 
 
 def test_resolve_backend_every_builtin_layout_is_fused_capable():
-    """Since the huffman in-kernel LUT decode, every built-in layout serves
-    through the fused backend when asked."""
+    """Off-TPU every built-in layout serves through the fused backend when
+    asked, huffman's in-kernel LUT decode included (interpret mode); on a
+    TPU huffman is the exception (see the huffman test below)."""
     for layout in LAYOUTS:
         assert ops.resolve_backend("fused", layouts.get_layout(layout)) == "fused"
 
@@ -162,10 +163,10 @@ def test_resolve_backend_every_builtin_layout_is_fused_capable():
 def test_non_fused_layout_has_no_tile_spec_and_kernel_entry_rejects(rng):
     """supports_fused=False is authoritative even when a layout inherits a
     fused-capable base's _tile_decode (a custom layout subclassing packed
-    with a different slot encoding): the tile spec must be None, a fused
-    request must fall back to the blockwise floor, and the direct kernel
-    entry must raise — not silently unpack the slots with the packed
-    decoder."""
+    with a different slot encoding): the tile spec must be None, auto must
+    pick the blockwise floor, an explicit fused request and the direct
+    kernel entry must raise — not silently serve another path or unpack
+    the slots with the packed decoder."""
 
     class _Ragged(layouts.PackedLayout):
         supports_fused = False
@@ -173,7 +174,11 @@ def test_non_fused_layout_has_no_tile_spec_and_kernel_entry_rejects(rng):
     layouts.register_layout("_test_ragged")(_Ragged)
     try:
         lay = layouts.get_layout("_test_ragged")
-        assert ops.resolve_backend("fused", lay) == "xla"
+        assert ops.resolve_backend("auto", lay) == "xla"
+        with mock.patch.dict(os.environ, {ops.ENV_BACKEND: "fused"}):
+            assert ops.resolve_backend("auto", lay) == "xla"
+        with pytest.raises(ValueError, match="cannot serve layout"):
+            ops.resolve_backend("fused", lay)
         spec = C.CacheSpec(layout="_test_ragged", block_size=16, max_seq=64)
         assert spec.impl.tile_decode(spec, 16) is None
         k, v, q = _mk(rng, 1, 2, 2, 32, 16)
@@ -347,8 +352,8 @@ def test_huffman_tile_decode_bit_exact_vs_blockwise_decode(rng):
     tile = lay.tile_decode(spec, D)
     assert tile is not None and len(tile.aux) == 2
     aux = tuple(jnp.asarray(a) for a in tile.aux)
-    k_codes = lay._decode(spec, cache.k_store, D, lay.book_k(spec))
-    v_codes = lay._decode(spec, cache.v_store, D, lay.book_v(spec))
+    k_codes = lay.decode_codes(spec, cache.k_store, D, "k")
+    v_codes = lay.decode_codes(spec, cache.v_store, D, "v")
     for b in range(2):
         for h in range(2):
             for n in range(4):
@@ -395,3 +400,21 @@ def test_spec_backend_dispatch_respected(rng):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(C.attend_blockwise(cache, q)),
                                atol=5e-3)
+
+
+def test_huffman_is_not_fused_on_tpu_and_explicit_fused_raises(monkeypatch):
+    """The one place the huffman/TPU decision lives: off-TPU the LUT decode
+    runs in the (interpreted) kernel; on a TPU auto picks the blockwise scan
+    and an explicit fused request raises instead of quietly serving xla."""
+    from repro.kernels import runtime
+
+    lay = layouts.get_layout("huffman")
+    assert lay.supports_fused
+    assert ops.resolve_backend("fused", lay) == "fused"
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    assert not lay.supports_fused
+    assert ops.resolve_backend("auto", lay) == "xla"
+    with pytest.raises(ValueError, match="cannot serve layout 'huffman'"):
+        ops.resolve_backend("fused", lay)
+    for name in ("raw", "packed", "kivi"):
+        assert ops.resolve_backend("auto", layouts.get_layout(name)) == "fused"

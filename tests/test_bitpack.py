@@ -1,4 +1,4 @@
-"""Bit-packing roundtrips: straddle, no-straddle, adaptive (DESIGN.md §2)."""
+"""Bit-packing roundtrips: straddle, planar no-straddle, adaptive (DESIGN.md §2)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -21,12 +21,19 @@ def test_straddle_roundtrip(bits, rng):
 @pytest.mark.parametrize("bits", range(1, 17))
 def test_nostraddle_roundtrip(bits, rng):
     hi = 1 << min(bits, 8)
-    c = rng.integers(0, hi, size=(3, 130)).astype(np.uint8)
-    w = bitpack.pack_nostraddle(jnp.asarray(c), bits)
-    u = bitpack.unpack_nostraddle(w, bits, 130)
+    c = rng.integers(0, hi, size=(3, 65, 24)).astype(np.uint8)
+    w = bitpack.pack_planar(jnp.asarray(c), bits)
+    u = bitpack.unpack_planar(w, bits, 65)
     assert (np.asarray(u) == c).all()
-    # no-straddle wastes at most (32 mod bits) bits per word
-    assert w.shape[-1] == bitpack.nostraddle_words(130, bits)
+    # one column per word, floor(32/bits) rows per word
+    assert w.shape == (3, bitpack.planar_rows(65, bits), 24)
+    # word[r, d] holds row i*R + r at bit i*bits (the format's definition)
+    R = w.shape[1]
+    wn = np.asarray(w)
+    for i in range(-(-65 // R)):
+        rows = np.arange(i * R, min((i + 1) * R, 65))
+        got = (wn[:, rows - i * R] >> (i * bits)) & ((1 << bits) - 1)
+        assert (got == c[:, rows]).all()
 
 
 @settings(max_examples=25, deadline=None)
@@ -34,9 +41,9 @@ def test_nostraddle_roundtrip(bits, rng):
        n=st.integers(1, 200))
 def test_nostraddle_property(seed, bits, n):
     rng = np.random.default_rng(seed)
-    c = rng.integers(0, 1 << bits, size=(2, n)).astype(np.uint8)
-    w = bitpack.pack_nostraddle(jnp.asarray(c), bits)
-    assert (np.asarray(bitpack.unpack_nostraddle(w, bits, n)) == c).all()
+    c = rng.integers(0, 1 << bits, size=(2, n, 3)).astype(np.uint8)
+    w = bitpack.pack_planar(jnp.asarray(c), bits)
+    assert (np.asarray(bitpack.unpack_planar(w, bits, n)) == c).all()
 
 
 @settings(max_examples=20, deadline=None)
@@ -66,8 +73,9 @@ def test_adaptive_bits_follow_range(rng):
 
 
 def test_packed_words_vs_nostraddle():
-    # straddle is denser, no-straddle is gather-free; both bounded
+    # straddle is denser, planar no-straddle is gather-free; both bounded
+    # (planar rounds the rows up to a whole word row per column)
     for bits in range(1, 9):
-        dense = bitpack.packed_words(1000, bits)
-        loose = bitpack.nostraddle_words(1000, bits)
-        assert dense <= loose <= dense + (1000 // (32 // bits)) + 1
+        dense = bitpack.packed_words(1000 * 8, bits)
+        loose = bitpack.planar_rows(1000, bits) * 8
+        assert dense <= loose <= dense + (1000 // (32 // bits) + 1) * 8

@@ -45,6 +45,12 @@ def test_dryrun_decode_record_builder_smoke(tmp_path, monkeypatch):
 
 
 def test_cost_numbers_normalizes_list_and_dict():
+    """cost_numbers reads what the installed jax's cost_analysis returns,
+    for a donated-argument step like the decode steps it is used on, and
+    reads absent keys as 0."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.launch import dryrun
 
     class _C:
@@ -56,5 +62,7 @@ def test_cost_numbers_normalizes_list_and_dict():
 
     d = {"flops": 3.0, "bytes accessed": 7.0}
     assert dryrun.cost_numbers(_C(d)) == {"flops": 3.0, "bytes": 7.0}
-    assert dryrun.cost_numbers(_C([d])) == {"flops": 3.0, "bytes": 7.0}
-    assert dryrun.cost_numbers(_C([])) == {"flops": 0.0, "bytes": 0.0}
+    assert dryrun.cost_numbers(_C({})) == {"flops": 0.0, "bytes": 0.0}
+    step = jax.jit(lambda x: x * 2.0, donate_argnums=0)
+    got = dryrun.cost_numbers(step.lower(jnp.ones(1024)).compile())
+    assert got["flops"] > 0 and got["bytes"] > 0

@@ -427,3 +427,30 @@ def test_trace_off_zero_events_zero_extra_dispatches(setup):
     assert len(full_server.trace.events) > 0
     assert off_n == full_n
     assert off_toks == full_toks
+
+
+def test_trace_capture_raises_when_the_trace_cannot_start(monkeypatch, tmp_path):
+    """A run asked for a profile must not exit 0 without one."""
+    def refuse(_):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with obs.trace_capture(str(tmp_path)):
+            pass
+    with obs.trace_capture(None):  # off: no profiler call at all
+        pass
+
+
+def test_trace_capture_raises_when_the_trace_cannot_stop(monkeypatch, tmp_path):
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace", calls.append)
+
+    def refuse():
+        raise RuntimeError("stop failed")
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", refuse)
+    with pytest.raises(RuntimeError, match="stop failed"):
+        with obs.trace_capture(str(tmp_path)):
+            pass
+    assert calls == [str(tmp_path)]

@@ -34,6 +34,16 @@ from repro.models.config import ModelConfig
 from repro.serve.scheduler import Request, Server, ServerConfig
 
 
+@pytest.fixture(autouse=True)
+def _drop_executables_per_test():
+    """This module compiles a few Servers' worth of executables per test;
+    left to accumulate over the whole module, XLA's CPU compiler segfaults
+    in a later compile that succeeds in a fresh process (the last test
+    here did).  No compiled function is shared between these tests."""
+    yield
+    jax.clear_caches()
+
+
 # ---------------------------------------------------------------------------
 # CacheSpec / policy validation (satellites)
 # ---------------------------------------------------------------------------
