@@ -104,11 +104,12 @@ def serve(cfg, params, *, max_slots: int = 8, max_seq: int = 4096,
     ``server.stats()["prefill"]`` reports chunks in flight and tokens
     co-scheduled with decode.
     ``trace`` (DESIGN.md §14) turns on the ring-buffered scheduler event
-    trace ("events" records every scheduling decision, "full" adds decode
-    dispatch spans); ``server.trace.write_chrome(path)`` — or
+    trace ("events" records every scheduling decision);
+    ``server.trace.write_chrome(path)`` — or
     ``server.shutdown(trace_out=...)`` — exports it as Perfetto-loadable
     Chrome trace-event JSON, and ``server.metrics`` is the typed registry
-    behind ``server.stats()``.
+    behind ``server.stats()`` (its ``step`` block times each step's host
+    phases, always on).
     Request-lifecycle hardening (DESIGN.md §15): failures are isolated —
     pool exhaustion with nothing reclaimable requeues the affected request
     up to ``max_requeues`` times (the same budget caps preemption storms,

@@ -146,14 +146,13 @@ def main(argv=None):
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N "
                          "first")
     ap.add_argument("--trace", default="off",
-                    choices=["off", "events", "full"],
+                    choices=["off", "events"],
                     help="scheduler event trace (DESIGN.md §14): events "
                          "records every scheduling decision in a ring "
-                         "buffer, full adds decode dispatch spans; off "
-                         "keeps the hot path event-free")
+                         "buffer; off keeps the hot path event-free")
     ap.add_argument("--trace-out", default=None,
                     help="write the event trace as Chrome trace-event JSON "
-                         "(open in Perfetto; needs --trace events|full)")
+                         "(open in Perfetto; needs --trace events)")
     ap.add_argument("--metrics-out", default=None,
                     help="write the metrics snapshot as JSON here, plus a "
                          ".prom Prometheus-text sibling")

@@ -16,11 +16,13 @@ Three pillars, one import:
   index, sharded pools) routes through one registry whose ``snapshot()``
   is the documented ``Server.stats()`` tree.
 * ``obs.trace`` — ``EventTrace``: ring-buffered scheduler decisions
-  (``ServerConfig.trace=off|events|full``) exportable as Chrome
-  trace-event JSON with per-request tracks.
-* ``obs.profiling`` — ``annotate`` / ``annotation`` / ``trace_capture``:
-  named scopes on the compression kernels and opt-in ``jax.profiler``
-  capture.
+  (``ServerConfig.trace=off|events``) exportable as Chrome trace-event
+  JSON with per-request tracks, on the profiler's clock.
+* ``obs.profiling`` — ``annotate`` / ``Phases`` / ``trace_capture``:
+  named scopes on the compression kernels, the
+  ``Server.step`` phases (``kvcomp:<phase>`` profiler spans plus
+  ``serve.step.*`` self-time counters, always on), and opt-in
+  ``jax.profiler`` capture.
 """
 
 from __future__ import annotations
@@ -28,13 +30,15 @@ from __future__ import annotations
 from repro.obs.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, LATENCY_BUCKETS_S,
 )
-from repro.obs.profiling import annotate, annotation, trace_capture  # noqa: F401
+from repro.obs.profiling import (  # noqa: F401
+    Phases, annotate, trace_capture,
+)
 from repro.obs.trace import EVENT_KINDS, TRACE_LEVELS, Event, EventTrace  # noqa: F401
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "LATENCY_BUCKETS_S",
     "EventTrace", "Event", "EVENT_KINDS", "TRACE_LEVELS",
-    "annotate", "annotation", "trace_capture",
+    "Phases", "annotate", "trace_capture",
     "format_snapshot", "bench_columns", "BENCH_COLUMNS",
 ]
 
@@ -100,6 +104,14 @@ def format_snapshot(stats: dict) -> str:
             line += (f" refs_total={pl['refs_total']} "
                      f"pages_shared={pl['pages_shared']}")
         lines.append(line)
+    st = stats.get("step")
+    if st and st["steps"]:
+        per = " ".join(f"{k[:-2]}={v / st['steps'] * 1e3:.2f}"
+                       for k, v in st.items()
+                       if k.endswith("_s") and k != "wall_s")
+        lines.append(f"  step: steps={st['steps']} "
+                     f"decode_steps={st['decode_steps']} "
+                     f"wall={st['wall_s']:.2f}s ms/step {per}")
     if "trace" in stats:
         tr = stats["trace"]
         lines.append(f"  trace[{tr['level']}]: events={tr['events']} "

@@ -7,7 +7,7 @@ over four modules (``Server._pf`` / ``_pfx`` / ``preemptions``,
 pool's per-shard copies), each surfaced through a differently shaped
 ``stats()`` dict.  This module is the one vocabulary they all route through:
 
-* ``Counter`` — monotone event count (``inc``).
+* ``Counter`` — monotone total (``inc``): events, or float seconds.
 * ``Gauge``   — last-written level (``set``) with a ``set_max`` hook for
   high-water marks.
 * ``Histogram`` — fixed-bucket distribution for latencies.  The bucket
@@ -42,7 +42,9 @@ LATENCY_BUCKETS_S = tuple(1e-4 * (1.9 ** i) for i in range(22))
 
 
 class Counter:
-    """Monotonically increasing event count."""
+    """Monotonically increasing total: an event count, or a sum of
+    non-negative amounts (``inc`` adds any number, so seconds accumulate
+    as floats)."""
 
     __slots__ = ("value",)
 

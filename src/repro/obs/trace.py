@@ -35,21 +35,24 @@ Event vocabulary (``kind`` / required payload):
                    effective bound it exceeded)
 ``token``          one generated token (``t`` = its ``token_times`` stamp,
                    ``index`` = its position in the stream)
-``decode_step``    one batched decode dispatch (level ``full`` only;
-                   ``rows`` = live batch width, ``dur`` = host wall)
 =================  ==========================================================
 
 Levels: ``off`` records nothing (the Server skips the call sites entirely —
 zero events, zero added dispatches), ``events`` records every scheduler
-decision above except the per-step firehose, ``full`` adds ``decode_step``.
-The buffer is a ``deque(maxlen=capacity)``: a long run keeps the most
-recent window and counts what it dropped instead of growing without bound.
+decision above.  Where each step's host time goes is not an event: the
+step's phases are always-on profiler spans and counters
+(``obs.profiling.Phases``).  The buffer is a ``deque(maxlen=capacity)``: a
+long run keeps the most recent window and counts what it dropped instead
+of growing without bound.
 
 ``to_chrome()`` exports the ring as Chrome trace-event JSON ("traceEvents"
 array, microsecond timestamps) that loads directly in Perfetto /
 ``chrome://tracing``: one named track (tid) per request carrying its
 queue -> prefill-chunk -> decode spans plus token/preempt instants, and a
-``scheduler`` track (tid 0) for row-addressed pool events.
+``scheduler`` track (tid 0) for row-addressed pool events.  Its timestamps
+are on the profiler's clock (wall-clock time; the events keep their
+``time.monotonic`` stamps), so the tracks line up with a ``jax.profiler``
+trace of the same run.
 """
 
 from __future__ import annotations
@@ -60,12 +63,12 @@ import time
 
 __all__ = ["Event", "EventTrace", "TRACE_LEVELS", "EVENT_KINDS"]
 
-TRACE_LEVELS = ("off", "events", "full")
+TRACE_LEVELS = ("off", "events")
 
 EVENT_KINDS = (
     "submit", "admit", "prefill_start", "prefill_chunk", "prefill_finish",
     "page_assign", "cow_break", "prefix_hit", "prefix_evict",
-    "preempt", "retire", "fail", "cancel", "deadline", "token", "decode_step",
+    "preempt", "retire", "fail", "cancel", "deadline", "token",
 )
 
 # Kinds that end a request's lifecycle (DESIGN.md §15 state machine); the
@@ -93,10 +96,6 @@ class EventTrace:
     @property
     def enabled(self) -> bool:
         return self.level != "off"
-
-    @property
-    def full(self) -> bool:
-        return self.level == "full"
 
     @property
     def dropped(self) -> int:
@@ -150,7 +149,13 @@ class EventTrace:
         in Perfetto (ui.perfetto.dev) or ``chrome://tracing``.  Requests
         become named threads of one ``kvcomp.server`` process; derived
         spans (queue, prefill, decode) are synthesized from the event pairs
-        so the timeline reads without knowing the vocabulary."""
+        so the timeline reads without knowing the vocabulary.
+
+        Every ``ts`` is on the profiler's host clock, which is wall-clock
+        time: the monotonic stamp plus one offset (``time.time_ns()`` less
+        ``time.monotonic_ns()``), taken once here and recorded in
+        ``otherData["clock_offset_us"]``."""
+        offset_us = (time.time_ns() - time.monotonic_ns()) / 1e3
         evs: list[dict] = [{
             "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
             "args": {"name": "kvcomp.server"},
@@ -158,6 +163,9 @@ class EventTrace:
             "name": "thread_name", "ph": "M", "pid": pid, "tid": 0,
             "args": {"name": "scheduler"},
         }]
+
+        def ts(t: float) -> float:
+            return t * 1e6 + offset_us
 
         def us(t: float) -> float:
             return t * 1e6
@@ -173,9 +181,9 @@ class EventTrace:
         for e in self.events:
             tid = e.req + 1 if e.req >= 0 else 0
             args = {"step": e.step, **e.data}
-            if e.kind in ("prefill_chunk", "decode_step"):
+            if e.kind == "prefill_chunk":
                 evs.append({"name": e.kind, "ph": "X", "pid": pid, "tid": tid,
-                            "ts": us(e.t), "dur": us(e.data.get("dur", 0.0)),
+                            "ts": ts(e.t), "dur": us(e.data.get("dur", 0.0)),
                             "args": args})
                 continue
             if e.req >= 0:
@@ -192,24 +200,25 @@ class EventTrace:
                 elif e.kind in _TERMINAL_KINDS:
                     s["retire"] = e.t
             evs.append({"name": e.kind, "ph": "i", "pid": pid, "tid": tid,
-                        "ts": us(e.t), "s": "t", "args": args})
+                        "ts": ts(e.t), "s": "t", "args": args})
 
         for r, s in spans.items():
             sub, work = s.get("submit"), s.get("work")
             end = s.get("retire", s.get("last"))
             if sub is not None and work is not None:
                 evs.append({"name": "queue", "ph": "X", "pid": pid,
-                            "tid": r + 1, "ts": us(sub),
+                            "tid": r + 1, "ts": ts(sub),
                             "dur": us(work - sub), "args": {}})
             dec = s.get("decode")
             if dec is not None and end is not None and end >= dec:
                 evs.append({"name": "decode", "ph": "X", "pid": pid,
-                            "tid": r + 1, "ts": us(dec),
+                            "tid": r + 1, "ts": ts(dec),
                             "dur": us(end - dec), "args": {}})
         return {"traceEvents": evs,
                 "displayTimeUnit": "ms",
                 "otherData": {"dropped_events": self.dropped,
-                              "level": self.level}}
+                              "level": self.level,
+                              "clock_offset_us": offset_us}}
 
     def write_chrome(self, path) -> None:
         with open(path, "w") as f:

@@ -88,10 +88,25 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pool as blockpool
-from repro.obs import EventTrace, MetricsRegistry
+from repro.obs import EventTrace, MetricsRegistry, Phases
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.serve.faults import InvariantAuditor, QueueFull, ServeError
+
+# The host phases of one ``Server.step`` (DESIGN.md §14): each is a
+# ``kvcomp:<name>`` profiler span and a ``serve.step.<name>_s`` self-time
+# counter, and their self times partition the step's wall time.
+STEP_PHASES = (
+    "step",             # the whole step, less the phases below
+    "lifecycle",        # deadline sweep, periodic audit, stall detector
+    "admit",            # queue pops, _can_admit, _start_prefill / _admit
+    "prefill",          # dispatch of a prefill chunk, with its inputs
+    "prefill_sync",     # the finishing chunk's first token to the host
+    "page_sweep",       # page faults of the decode step and of chunks
+    "decode_dispatch",  # the batched decode step's dispatch
+    "token_sync",       # the decode step's tokens to the host
+    "retire",           # the loop over rows: push, retire, release
+)
 
 
 @dataclasses.dataclass
@@ -202,7 +217,7 @@ class ServerConfig:
     #   "events" — every scheduler decision: admit, prefill chunk splice,
     #              page-fault sweep outcome, CoW break, prefix hit/evict,
     #              preempt/requeue, retire, token emission.
-    #   "full"   — "events" plus the per-step decode-dispatch firehose.
+    # The step's phase spans and counters (STEP_PHASES) are always on.
     trace: str = "off"
     # Ring capacity of the event trace; a longer run keeps the most recent
     # window and reports how many events it dropped.
@@ -246,9 +261,8 @@ class ServerConfig:
             raise ValueError(
                 "prefill_chunk_tokens must be a positive multiple of the "
                 f"cache block_size, got {self.prefill_chunk_tokens}")
-        if self.trace not in ("off", "events", "full"):
-            raise ValueError(
-                f"trace must be off|events|full, got {self.trace!r}")
+        if self.trace not in ("off", "events"):
+            raise ValueError(f"trace must be off|events, got {self.trace!r}")
         if self.max_requeues < 0:
             raise ValueError(
                 f"max_requeues must be >= 0, got {self.max_requeues}")
@@ -455,6 +469,9 @@ class Server:
         self._h_queue = self.metrics.histogram("serve.queue_wait_s")
         self._g_active = self.metrics.gauge("serve.active")
         self._g_pending = self.metrics.gauge("serve.pending")
+        self._phase = Phases(self.metrics, "serve.step", STEP_PHASES)
+        self._steps = self.metrics.counter("serve.step.steps")
+        self._decode_steps = self.metrics.counter("serve.step.decode_steps")
         # Chunked admission (DESIGN.md §13): PREFILLING rows by slot index.
         # A slot is busy while it appears in EITHER _slots or _prefill_tasks.
         self._prefill_tasks: dict[int, _PrefillTask] = {}
@@ -470,7 +487,6 @@ class Server:
                               "requeues", "rejected")}
         self.faults = scfg.faults
         self.auditor = InvariantAuditor(self) if scfg.audit_every else None
-        self._steps = 0
         self._progress = 0   # bumps on any token/chunk/admission/retirement
         self._stall = 0      # consecutive zero-progress steps with work left
         self._has_deadlines = scfg.default_deadline_s is not None
@@ -1003,7 +1019,6 @@ class Server:
         this path for lossy layouts (chunks attend earlier blocks through
         the compressed store, full-length prefill attends them raw)."""
         req = handle.request
-        prompt = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
         if any(s is not None for s in self._slots):
             # Solo admission freezes every live decode stream for the whole
             # prompt — the stall chunked admission exists to kill.
@@ -1014,8 +1029,11 @@ class Server:
             self._h_queue.observe(t0 - handle._t_submit)
         if self._tr is not None:
             self._tr.emit("admit", req=handle.id, t=t0, row=row)
-        first_tok, solo = self._prefill(self.params, prompt)
-        first = int(first_tok[0])
+        with self._phase("prefill"):
+            prompt = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
+            first_tok, solo = self._prefill(self.params, prompt)
+        with self._phase("prefill_sync"):
+            first = int(first_tok[0])
         t1 = time.monotonic()
         # Accumulate across preemption replays: prefill_s sums every prompt
         # (re)play and t_start keeps the FIRST admission, so Result.gen_s is
@@ -1192,19 +1210,23 @@ class Server:
             while kb * 2 <= k:
                 kb *= 2
             if kb >= 2:
-                if fused and not all(self._ensure_chunk_page(task, pos + j * T)
-                                     for j in range(kb)):
-                    break  # the reclaim preempted this very task
-                tc = time.monotonic() if self._tr is not None else 0.0
-                t = jnp.asarray(
-                    task.forced[pos:pos + kb * T].reshape(kb, 1, T))
                 if fused:
-                    pages = jnp.asarray(self._pt_host[row], jnp.int32)
-                    tok, self.state = self._chunk_paged_scan(
-                        self.params, t, jnp.int32(pos), self.state, pages)
-                else:
-                    tok, task.state = self._chunk_scan(
-                        self.params, t, jnp.int32(pos), task.state)
+                    with self._phase("page_sweep"):
+                        paged = all(self._ensure_chunk_page(task, pos + j * T)
+                                    for j in range(kb))
+                    if not paged:
+                        break  # the reclaim preempted this very task
+                tc = time.monotonic() if self._tr is not None else 0.0
+                with self._phase("prefill"):
+                    t = jnp.asarray(
+                        task.forced[pos:pos + kb * T].reshape(kb, 1, T))
+                    if fused:
+                        pages = jnp.asarray(self._pt_host[row], jnp.int32)
+                        tok, self.state = self._chunk_paged_scan(
+                            self.params, t, jnp.int32(pos), self.state, pages)
+                    else:
+                        tok, task.state = self._chunk_scan(
+                            self.params, t, jnp.int32(pos), task.state)
                 if self._tr is not None:
                     self._tr.emit("prefill_chunk", req=handle.id, t=tc,
                                   dur=time.monotonic() - tc, row=row,
@@ -1219,25 +1241,29 @@ class Server:
                         T * (pos + j * T) + T * (T + 1) // 2
                         for j in range(kb)))
                 if task.pos == task.n:
-                    self._finish_task(task, int(np.asarray(tok)[0]))
+                    self._finish_task(task, self._first_token(tok))
                     break
                 continue
-            if fused and C == T and not self._ensure_chunk_page(task, pos):
-                break  # the reclaim preempted this very task
+            if fused and C == T:
+                with self._phase("page_sweep"):
+                    paged = self._ensure_chunk_page(task, pos)
+                if not paged:
+                    break  # the reclaim preempted this very task
             tc = time.monotonic() if self._tr is not None else 0.0
-            t = jnp.asarray(task.forced[None, pos:pos + C])
-            if fused:
-                pages = jnp.asarray(self._pt_host[row], jnp.int32)
-                if pos + C == task.n:
-                    tok, self.state = self._finish_paged(
-                        self.params, t, jnp.int32(pos), self.state, pages,
-                        jnp.int32(row))
+            with self._phase("prefill"):
+                t = jnp.asarray(task.forced[None, pos:pos + C])
+                if fused:
+                    pages = jnp.asarray(self._pt_host[row], jnp.int32)
+                    if pos + C == task.n:
+                        tok, self.state = self._finish_paged(
+                            self.params, t, jnp.int32(pos), self.state, pages,
+                            jnp.int32(row))
+                    else:
+                        tok, self.state = self._chunk_paged(
+                            self.params, t, jnp.int32(pos), self.state, pages)
                 else:
-                    tok, self.state = self._chunk_paged(
-                        self.params, t, jnp.int32(pos), self.state, pages)
-            else:
-                tok, task.state = self._chunk(self.params, t, jnp.int32(pos),
-                                              task.state)
+                    tok, task.state = self._chunk(
+                        self.params, t, jnp.int32(pos), task.state)
             if self._tr is not None:
                 self._tr.emit("prefill_chunk", req=handle.id, t=tc,
                               dur=time.monotonic() - tc, row=row,
@@ -1251,12 +1277,17 @@ class Server:
                 self._pfx["prefill_attn_pairs"].inc(
                     C * pos + C * (C + 1) // 2)
             if task.pos == task.n:
-                self._finish_task(task, int(np.asarray(tok)[0]))
+                self._finish_task(task, self._first_token(tok))
                 break
         handle._prefill_s += time.monotonic() - t0
         self._pf["prefill_tokens"].inc(spent)
         self._progress += spent
         return spent
+
+    def _first_token(self, tok) -> int:
+        """The finishing chunk's next greedy token, on the host."""
+        with self._phase("prefill_sync"):
+            return int(np.asarray(tok)[0])
 
     def _finish_task(self, task: _PrefillTask, first: int) -> None:
         """The finishing chunk ran: the row's cache holds all ``n`` forced
@@ -1612,20 +1643,32 @@ class Server:
         ``ServeError`` (naming the stuck request) instead of letting
         ``Handle.result()`` spin forever on a stuck server — except while
         an unexpired deadline is pending, because wall-clock time will
-        retire that request even if nothing else moves."""
-        self._sweep_deadlines()
-        p0 = self._progress
-        more = self._step_inner()
-        self._steps += 1
+        retire that request even if nothing else moves.
+
+        The step's host phases (``STEP_PHASES``) partition its wall time
+        into profiler spans and ``serve.step.*`` counters."""
+        with self._phase("step"):
+            with self._phase("lifecycle"):
+                self._sweep_deadlines()
+            p0 = self._progress
+            more = self._step_inner()
+            self._steps.inc()
+            with self._phase("lifecycle"):
+                self._check_progress(more, p0)
+        return more
+
+    def _check_progress(self, more: bool, p0: int) -> None:
+        """The periodic invariant audit and the no-progress detector, after
+        a step that started at progress ``p0``."""
         if (self.auditor is not None
-                and self._steps % self.scfg.audit_every == 0):
+                and self._steps.value % self.scfg.audit_every == 0):
             self.auditor.check()
         if not more or self._progress != p0:
             self._stall = 0
-            return more
+            return
         if any(self._deadline_of(h) is not None for h in self._all_handles()):
             self._stall = 0
-            return more
+            return
         self._stall += 1
         if self._stall >= self.scfg.stall_steps:
             stuck = min(self._all_handles(), key=lambda h: h.id)
@@ -1642,7 +1685,6 @@ class Server:
                 f"stuck (active={self.active}, "
                 f"prefilling={self.prefilling}, pending={self.pending}"
                 f"{pool})")
-        return more
 
     def _step_inner(self) -> bool:
         """Admit whatever fits (slot- AND, in paged mode, memory-pressure-
@@ -1665,6 +1707,46 @@ class Server:
         pf_budget = self._chunk_budget if self.prefill_chunked else 0
         if self._prefill_tasks:
             pf_budget = self._run_prefill_budget(pf_budget, decoding)
+        if free and self._queue:
+            with self._phase("admit"):
+                self._admit_queued(free, decoding, pf_budget)
+        if self.paged:
+            with self._phase("page_sweep"):
+                self._ensure_pages()
+        rows = [i for i, s in enumerate(self._slots) if s is not None]
+        if not rows:
+            return bool(self._queue) or bool(self._prefill_tasks)
+        if self._fault("decode_dispatch"):
+            # Injected transient dispatch failure: skip this step's batched
+            # decode BEFORE calling the donating closure (the live state
+            # survives untouched); next step retries with identical inputs,
+            # so greedy tokens are merely delayed, never changed.
+            return True
+        with self._phase("decode_dispatch"):
+            toks, self.state = self._decode(
+                self.params, jnp.asarray(self._cur), jnp.asarray(self._pos),
+                self.state)
+        self._decode_steps.inc()
+        with self._phase("token_sync"):
+            nxt = np.asarray(toks)
+        self._progress += len(rows)  # tokens produced this step
+        with self._phase("retire"):
+            for row in rows:
+                tok = int(nxt[row])
+                self._cur[row] = tok
+                self._pos[row] += 1
+                if self._slots[row]._push(tok):
+                    self._slots[row] = None  # retire; slot reused next step
+                    if self.paged:
+                        self._release_row(row)
+        return (bool(self._queue) or bool(self._prefill_tasks)
+                or any(s is not None for s in self._slots))
+
+    def _admit_queued(self, free: list[int], decoding: bool,
+                      pf_budget: int) -> None:
+        """Admit queued requests onto the ``free`` slots while they fit,
+        chunking new tasks through what is left of the step's prefill
+        budget."""
         while free and self._queue:
             handle = self._pop_next()
             # Admit onto the free slot whose data shard has the most free
@@ -1703,39 +1785,6 @@ class Server:
                     free.append(row)  # finished (and retired) at admission
             elif self._admit(handle, row):
                 free.remove(row)
-        if self.paged:
-            self._ensure_pages()
-        rows = [i for i, s in enumerate(self._slots) if s is not None]
-        if not rows:
-            return bool(self._queue) or bool(self._prefill_tasks)
-        if self._fault("decode_dispatch"):
-            # Injected transient dispatch failure: skip this step's batched
-            # decode BEFORE calling the donating closure (the live state
-            # survives untouched); next step retries with identical inputs,
-            # so greedy tokens are merely delayed, never changed.
-            return True
-        full = self._tr is not None and self._tr.full
-        td = time.monotonic() if full else 0.0
-        toks, self.state = self._decode(
-            self.params, jnp.asarray(self._cur), jnp.asarray(self._pos),
-            self.state)
-        nxt = np.asarray(toks)
-        if full:
-            # "full" firehose: one span per batched decode dispatch (the
-            # np.asarray above synced it, so dur covers device time too).
-            self._tr.emit("decode_step", t=td, dur=time.monotonic() - td,
-                          rows=len(rows))
-        self._progress += len(rows)  # tokens produced this step
-        for row in rows:
-            tok = int(nxt[row])
-            self._cur[row] = tok
-            self._pos[row] += 1
-            if self._slots[row]._push(tok):
-                self._slots[row] = None  # retire; slot reused next step
-                if self.paged:
-                    self._release_row(row)
-        return (bool(self._queue) or bool(self._prefill_tasks)
-                or any(s is not None for s in self._slots))
 
     def run(self) -> None:
         """Drain: step until every submitted request has finished."""
@@ -1784,6 +1833,11 @@ class Server:
                 "events": len(self.trace.events),
                 "dropped": self.trace.dropped,
             },
+            # Host time of the step by phase (STEP_PHASES; self seconds,
+            # summing to wall_s) and the steps that dispatched a decode.
+            "step": {"steps": self._steps.value,
+                     "decode_steps": self._decode_steps.value,
+                     **self._phase.snapshot()},
             # Terminal-state accounting (DESIGN.md §15): how many requests
             # ever entered, and every abnormal way one left.
             "lifecycle": {

@@ -11,14 +11,20 @@ The load-bearing contracts:
   EXACTLY (float-for-float): token events reuse the same monotonic stamps.
 * ``trace="off"`` records nothing and adds no device dispatches — greedy
   outputs are bit-identical to a traced run.
+* The always-on step phases partition ``Server.step``: their
+  ``serve.step.*`` self times sum to its wall time and agree with the
+  ``kvcomp:*`` spans of a ``jax.profiler`` trace, whose clock the Chrome
+  export shares.
 """
 
 import dataclasses
+import importlib.util
 import json
 import os
 import subprocess
 import sys
 import textwrap
+import time
 from pathlib import Path
 
 import jax
@@ -30,9 +36,11 @@ from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.trace import EventTrace
 from repro.models import model as M
 from repro.models import registry
-from repro.serve.scheduler import Request, Server, ServerConfig
+from repro.serve.scheduler import STEP_PHASES, Request, Server, ServerConfig
 
-SRC = str(Path(__file__).resolve().parents[1] / "src")
+ROOT = Path(__file__).resolve().parents[1]
+SRC = str(ROOT / "src")
+BENCH = ROOT / "bench"
 
 LENS = (7, 13, 19, 26)
 NEWS = (3, 6, 4, 5)
@@ -48,6 +56,8 @@ def test_counter_and_gauge():
     c.inc()
     c.inc(4)
     assert c.value == 5
+    c.inc(0.25)  # seconds accumulate as floats
+    assert c.value == 5.25
     g = Gauge()
     g.set(3.0)
     g.set_max(2.0)
@@ -113,8 +123,10 @@ def test_prometheus_text_exposition():
 def test_trace_levels_and_ring_drop():
     with pytest.raises(ValueError):
         EventTrace("verbose")
+    with pytest.raises(ValueError):
+        EventTrace("full")  # retired: the step phases time each step
     tr = EventTrace("events", capacity=4)
-    assert tr.enabled and not tr.full
+    assert tr.enabled
     for i in range(10):
         tr.emit("token", req=0, t=float(i), index=i)
     assert len(tr.events) == 4
@@ -139,26 +151,34 @@ def test_request_timings_reconstruction_synthetic():
 
 
 def test_chrome_export_structure_synthetic():
-    tr = EventTrace("full")
+    tr = EventTrace("events")
     tr.emit("submit", req=0, t=1.0)
     tr.emit("prefill_start", req=0, t=1.2, row=0)
     tr.emit("prefill_chunk", req=0, t=1.25, dur=0.05, row=0, pos=0, tokens=8)
     tr.emit("token", req=0, t=1.5, index=0)
     tr.emit("retire", req=0, t=1.6, reason="length")
-    tr.emit("decode_step", t=1.4, dur=0.01, rows=1)
+    before = (time.time_ns() - time.monotonic_ns()) / 1e3
     doc = tr.to_chrome()
+    after = (time.time_ns() - time.monotonic_ns()) / 1e3
     evs = doc["traceEvents"]
     assert {e["ph"] for e in evs} == {"M", "X", "i"}
     names = {e["name"] for e in evs}
     # metadata tracks + raw events + synthesized queue/decode spans
-    assert {"process_name", "thread_name", "prefill_chunk", "decode_step",
+    assert {"process_name", "thread_name", "prefill_chunk",
             "queue", "decode"} <= names
     track = [e for e in evs if e["name"] == "thread_name"
              and e["tid"] == 1][0]
     assert track["args"]["name"] == "req 0"
+    # Every ts is the monotonic stamp moved onto the profiler's (wall)
+    # clock by the one offset the export records.
+    offset = doc["otherData"]["clock_offset_us"]
+    assert before - 1e3 <= offset <= after + 1e3
     queue = [e for e in evs if e["name"] == "queue"][0]
-    assert queue["ts"] == pytest.approx(1.0e6)
+    assert queue["ts"] == pytest.approx(1.0e6 + offset, abs=1.0)
     assert queue["dur"] == pytest.approx(0.2e6)
+    chunk = [e for e in evs if e["name"] == "prefill_chunk"][0]
+    assert chunk["ts"] == pytest.approx(1.25e6 + offset, abs=1.0)
+    assert chunk["dur"] == pytest.approx(0.05e6)
     json.dumps(doc)  # must be serializable as-is
 
 
@@ -192,10 +212,10 @@ def _run(cfg, params, prompts, **kw):
 
 @pytest.fixture(scope="module")
 def traced(setup):
-    """One paged + prefix-sharing server run under trace='full'."""
+    """One paged + prefix-sharing server run under trace='events'."""
     cfg, params, prompts = setup
     return _run(cfg, params, prompts, cache_mode="paged", prefix_cache="on",
-                trace="full")
+                trace="events")
 
 
 def key_tree(d):
@@ -209,6 +229,8 @@ def key_tree(d):
 
 
 LAT_KEYS = {"count", "sum", "mean", "min", "max", "p50", "p99"}
+STEP_KEYS = ({"steps", "decode_steps", "wall_s"}
+             | {f"{p}_s" for p in STEP_PHASES})
 
 
 def test_stats_schema_across_modes(setup):
@@ -226,7 +248,8 @@ def test_stats_schema_across_modes(setup):
         stats[(mode, pfx)] = server.stats()
     for (mode, pfx), st in stats.items():
         base = {"cache_mode", "active", "pending", "preemptions",
-                "prefill", "latency", "trace", "shards", "lifecycle"}
+                "prefill", "latency", "trace", "step", "shards",
+                "lifecycle"}
         want = base | ({"pool"} if mode == "paged" else set())
         want |= {"prefix"} if pfx != "off" else set()
         assert set(st) == want, (mode, pfx)
@@ -235,6 +258,9 @@ def test_stats_schema_across_modes(setup):
             assert set(st["latency"][h]) == LAT_KEYS
         assert st["latency"]["ttft_s"]["count"] == len(prompts)
         assert set(st["trace"]) == {"level", "events", "dropped"}
+        assert set(st["step"]) == STEP_KEYS
+        assert st["step"]["decode_steps"] <= st["step"]["steps"]
+        assert st["step"]["wall_s"] > 0
         sh = st["shards"]
         assert sh["n_data"] == 1 and len(sh["per_shard"]) == 1
         for p in sh["per_shard"]:
@@ -328,11 +354,18 @@ def test_trace_full_records_scheduler_vocabulary(traced):
     server, handles, results = traced
     kinds = {e.kind for e in server.trace.events}
     assert {"submit", "prefill_start", "prefill_chunk", "prefill_finish",
-            "token", "retire", "page_assign", "prefix_hit",
-            "decode_step"} <= kinds
+            "token", "retire", "page_assign", "prefix_hit"} <= kinds
     # every token of every result is in the ring (small run, no wrap)
     n_tok = sum(1 for e in server.trace.events if e.kind == "token")
     assert n_tok == sum(len(r.tokens) for r in results)
+    # The decode dispatches are the step counters' business, not events:
+    # every phase of this paged prefix-sharing run took time, and each
+    # decode step produced the first or a later token of some request.
+    st = server.stats()["step"]
+    assert all(st[f"{p}_s"] > 0 for p in STEP_PHASES)
+    assert 0 < st["decode_steps"] <= st["steps"]
+    n_decoded = sum(max(len(r.tokens) - 1, 0) for r in results)
+    assert st["decode_steps"] <= n_decoded
 
 
 def test_chrome_export_from_server(traced, tmp_path):
@@ -374,7 +407,7 @@ def test_format_snapshot_renders_all_sections(traced):
     server, _, _ = traced
     text = obs.format_snapshot(server.stats())
     for frag in ("serve[paged]", "lifecycle:", "prefill[", "latency:",
-                 "pool:", "shards:", "prefix[on]", "trace[full]"):
+                 "pool:", "shards:", "prefix[on]", "step:", "trace[events]"):
         assert frag in text, frag
 
 
@@ -403,10 +436,11 @@ def _count_dispatches(server) -> dict:
 
 def test_trace_off_zero_events_zero_extra_dispatches(setup):
     """trace='off' must cost nothing: no events, the same number of device
-    dispatches as a fully traced run, and bit-identical greedy tokens."""
+    dispatches as a traced run, and bit-identical greedy tokens (the
+    always-on step phases dispatch nothing either way)."""
     cfg, params, prompts = setup
     runs = {}
-    for level in ("off", "full"):
+    for level in ("off", "events"):
         server = Server(cfg, params,
                         ServerConfig(max_slots=2, max_seq=128,
                                      cache_mode="paged", prefix_cache="on",
@@ -419,14 +453,16 @@ def test_trace_off_zero_events_zero_extra_dispatches(setup):
         runs[level] = (server, counts["n"],
                        [h.result().tokens.tolist() for h in handles])
     off_server, off_n, off_toks = runs["off"]
-    full_server, full_n, full_toks = runs["full"]
+    ev_server, ev_n, ev_toks = runs["events"]
     assert len(off_server.trace.events) == 0
     assert off_server.trace.emitted == 0
     assert off_server.stats()["trace"] == {"level": "off", "events": 0,
                                            "dropped": 0}
-    assert len(full_server.trace.events) > 0
-    assert off_n == full_n
-    assert off_toks == full_toks
+    assert len(ev_server.trace.events) > 0
+    assert off_n == ev_n
+    assert off_toks == ev_toks
+    assert (off_server.stats()["step"]["decode_steps"]
+            == ev_server.stats()["step"]["decode_steps"])
 
 
 def test_trace_capture_raises_when_the_trace_cannot_start(monkeypatch, tmp_path):
@@ -454,3 +490,183 @@ def test_trace_capture_raises_when_the_trace_cannot_stop(monkeypatch, tmp_path):
         with obs.trace_capture(str(tmp_path)):
             pass
     assert calls == [str(tmp_path)]
+
+
+# ---------------------------------------------------------------------------
+# Step phases: profiler spans and self-time counters
+# ---------------------------------------------------------------------------
+
+
+def _trace_reduce():
+    """``bench/trace_reduce.py``, the benchmark's own trace reader."""
+    spec = importlib.util.spec_from_file_location(
+        "kvcomp_trace_reduce", BENCH / "trace_reduce.py")
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # its dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_phases_self_times_partition_the_outermost():
+    reg = MetricsRegistry()
+    ph = obs.Phases(reg, "loop", ("outer", "inner"))
+    assert ph.snapshot() == {"wall_s": 0, "outer_s": 0, "inner_s": 0}
+    for _ in range(3):
+        with ph("outer"):
+            time.sleep(0.002)
+            with ph("inner"):
+                time.sleep(0.004)
+                with ph("inner"):  # re-entrant: nested self time is its own
+                    time.sleep(0.001)
+    snap = ph.snapshot()
+    assert snap["inner_s"] >= 3 * 0.005 and snap["outer_s"] >= 3 * 0.002
+    assert snap["outer_s"] + snap["inner_s"] == pytest.approx(snap["wall_s"])
+    # The counters live in the registry, so the exporters see them.
+    assert reg.snapshot()["loop"]["inner_s"] == snap["inner_s"]
+    assert "# TYPE kvcomp_loop_wall_s counter" in reg.prometheus_text()
+    with pytest.raises(KeyError):
+        with ph("outer"):
+            raise KeyError("a phase that raises still closes")
+    assert ph._open == []
+
+
+@pytest.fixture(scope="module")
+def profiled(setup, tmp_path_factory):
+    """A paged + prefix-sharing run (trace='events') under a
+    ``jax.profiler`` trace: the server, its results, the trace's host spans
+    on the profiler's absolute clock (ns), and the Chrome export."""
+    cfg, params, prompts = setup
+    server = Server(cfg, params,
+                    ServerConfig(max_slots=2, max_seq=128,
+                                 cache_mode="paged", prefix_cache="on",
+                                 trace="events"),
+                    q_chunk=32, kv_chunk=32)
+    # Compile outside the trace, so the traced steps are steady ones.
+    server.submit(Request(prompt=prompts[0], max_new_tokens=2))
+    server.run()
+    before = server.stats()["step"]
+    log_dir = tmp_path_factory.mktemp("profile")
+    # Host spans only: the profiler's Python tracer hooks every call,
+    # including those between a span's edge and the phase's clock reading,
+    # which would time the tracer rather than the phases.
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        handles = [server.submit(Request(prompt=p, max_new_tokens=n))
+                   for p, n in zip(prompts, NEWS)]
+        server.run()
+    after = server.stats()["step"]
+    chrome = server.trace.to_chrome()
+    tr = _trace_reduce()
+    path = sorted(log_dir.rglob("*.xplane.pb"))[-1]
+    # The trace's times are ns since the profile started, on the wall clock.
+    env = jax.profiler.ProfileData.from_file(str(path)).find_plane_with_name(
+        "Task Environment")
+    start = dict(env.stats)["profile_start_time"]
+    spans = [(s + start, e + start, n) for s, e, n in tr.read_xspace(path).host
+             if n.startswith("kvcomp:")]
+    delta = {k: after[k] - before[k] for k in after}
+    return server, handles, spans, delta, chrome
+
+
+def _self_seconds(spans) -> dict:
+    """Self time of each ``kvcomp:`` span name: its duration less that of
+    the spans directly inside it (all on one thread)."""
+    out: dict = {}
+    stack: list = []
+    for s, e, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][1] <= s:
+            stack.pop()
+        if stack:
+            out[stack[-1][2]] = out.get(stack[-1][2], 0.0) - (e - s)
+        out[name] = out.get(name, 0.0) + (e - s)
+        stack.append((s, e, name))
+    return {n: v / 1e9 for n, v in out.items()}
+
+
+def test_every_phase_span_nests_inside_a_step_span(profiled):
+    _, _, spans, _, _ = profiled
+    names = {n for _, _, n in spans}
+    assert names == {f"kvcomp:{p}" for p in STEP_PHASES}
+    steps = sorted((s, e) for s, e, n in spans if n == "kvcomp:step")
+    assert steps
+    for s, e, name in spans:
+        if name != "kvcomp:step":
+            assert any(a <= s and e <= b for a, b in steps), name
+
+
+def test_phase_self_times_sum_to_the_step_wall(profiled):
+    server, _, _, delta, _ = profiled
+    total = sum(delta[f"{p}_s"] for p in STEP_PHASES)
+    assert total == pytest.approx(delta["wall_s"], rel=0.01)
+    st = server.stats()["step"]
+    assert sum(st[f"{p}_s"] for p in STEP_PHASES) == pytest.approx(
+        st["wall_s"], rel=0.01)
+
+
+def test_phase_counters_agree_with_their_spans(profiled):
+    _, _, spans, delta, _ = profiled
+    by_span = _self_seconds(spans)
+    for p in STEP_PHASES:
+        counted, seen = delta[f"{p}_s"], by_span[f"kvcomp:{p}"]
+        assert abs(counted - seen) <= max(0.05 * counted, 2e-4), (
+            p, counted, seen)
+    n_steps = sum(1 for _, _, n in spans if n == "kvcomp:step")
+    assert n_steps == delta["steps"]
+
+
+def test_chrome_export_lines_up_with_the_profiler(profiled):
+    """Shifted onto the profiler's clock, a decode token's ``ts`` falls in
+    (or within 2 ms of) a ``kvcomp:retire`` span, and a first token's in a
+    ``kvcomp:prefill_sync`` one: the phases that push them."""
+    _, handles, spans, _, chrome = profiled
+    near = {n: [(s, e) for s, e, m in spans if m == n]
+            for n in ("kvcomp:retire", "kvcomp:prefill_sync")}
+    ids = {h.id for h in handles}
+    tokens = [e for e in chrome["traceEvents"]
+              if e["name"] == "token" and e["tid"] - 1 in ids]
+    assert len(tokens) == sum(len(h.result().tokens) for h in handles)
+    for ev in tokens:
+        t = ev["ts"] * 1e3  # us -> ns
+        phase = ("kvcomp:prefill_sync" if ev["args"]["index"] == 0
+                 else "kvcomp:retire")
+        gap = min(max(s - t, t - e, 0.0) for s, e in near[phase])
+        assert gap <= 2e6, (ev, phase, gap)
+
+
+def _reader(name):
+    spec = importlib.util.spec_from_file_location(
+        "kvcomp_metric_" + name.replace(".", "_"),
+        BENCH / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_step_host_ms_reader_synthetic():
+    """``step_host_ms.longdoc``: (wall - token_sync - prefill_sync) over the
+    window's decode steps, in ms; nothing where the program has no
+    ``step`` counters (a program from before them)."""
+    sys.path.insert(0, str(BENCH))
+    try:
+        from kvbench.cell import Context
+    finally:
+        sys.path.remove(str(BENCH))
+
+    def step(steps, decode, wall, token_sync, prefill_sync):
+        return {"step": {"steps": steps, "decode_steps": decode,
+                         "wall_s": wall, "token_sync_s": token_sync,
+                         "prefill_sync_s": prefill_sync, "admit_s": 0.5}}
+
+    def ctx(a, b):
+        return Context(cell=None, arch=None, chips=1, peaks={}, t_open=0.0,
+                       t_close=51.0, records=[], stats_open=a, stats_close=b,
+                       stats_admit=a, kv={})
+
+    read = _reader("step_host_ms.longdoc")
+    a = step(100, 90, 10.0, 8.0, 0.5)
+    b = step(550, 540, 61.0, 56.0, 1.1)
+    # 51 s of wall, 48 s of token sync, 0.6 s of prefill sync, 450 steps
+    assert read(ctx(a, b)) == pytest.approx(1e3 * 2.4 / 450)
+    assert read(ctx(a, a)) is None  # no decode step in the window
+    assert read(ctx({"prefix": {}}, {"prefix": {}})) is None
