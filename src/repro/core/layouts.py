@@ -208,14 +208,15 @@ def dequant_v(codes: Array, mn: Array, st: Array) -> Array:
 class FusedTileSpec:
     """Layout-owned decode hook for the fused Pallas attention kernel.
 
-    The kernel (``repro.kernels.fused_kv_attn``) streams one store tile per
-    grid step HBM→VMEM and calls ``decode_k``/``decode_v`` to reconstruct the
-    dequantized ``[T, D]`` block in situ (DESIGN.md §9).  The decode callables
+    The kernel (``repro.kernels.fused_kv_attn``) gathers store tiles
+    HBM→VMEM, one DMA per page, and calls ``decode_k``/``decode_v`` once per
+    page to reconstruct the dequantized ``[T, D]`` block in situ (DESIGN.md
+    §9).  The decode callables
     must be kernel-safe (no captured host arrays, jnp ops only); they are also
     ``vmap``-ed over (B, H, NB) by the kernel's pure-jnp oracle, so one
     definition serves both paths.
 
-    k_tile / v_tile : per-block store tile shape (what one grid step loads):
+    k_tile / v_tile : per-block store tile shape (what one page DMA moves):
         always 2-D and equal to the store's two trailing dims, e.g.
         ``(Rk, D)`` planar words or ``(T, D)`` raw values — the Mosaic
         tiling rule every kernel operand obeys (DESIGN.md §9).

@@ -1,11 +1,12 @@
 """Pallas TPU kernel: fused in-situ decompression + flash-decode attention.
 
 The TPU realization of the paper's cache-resident decompression (§3.3.2):
-compressed store tiles stream HBM→VMEM once per block; decoding (layout-owned
-— see below), dequantization, and the attention matvec all happen inside the
-kernel on VMEM/VREG data.  The decompressed K/V tiles are never written back
-to HBM — exactly the paper's "decompressed data consumed in situ", with VMEM
-playing the role of GPU shared memory and the MXU taking the dot products.
+compressed store pages stream HBM→VMEM once per decode step; decoding
+(layout-owned — see below), dequantization, and the attention matvec all
+happen inside the kernel on VMEM/VREG data.  The decompressed K/V tiles are
+never written back to HBM — exactly the paper's "decompressed data consumed
+in situ", with VMEM playing the role of GPU shared memory and the MXU taking
+the dot products.
 
 The per-tile decode is NOT hardcoded to one layout: the kernel is
 parameterized by a ``repro.core.layouts.FusedTileSpec`` — the layout-owned
@@ -18,27 +19,44 @@ direct-lookup LUTs) the kernel stages into VMEM with constant index maps
 and appends to every decode call, while the per-stream u16 bit counts
 arrive as part of the fixed worst-case-padded slot tile itself.
 
-Grid: ``(B, Hkv, NB + 1)``.  TPU grids execute sequentially with the last
-axis innermost, so VMEM scratch carries the flash-decoding running state
-``(m, l, acc)`` across the block axis for each (batch, kv-head) pair.  The
-extra final step folds the raw append buffer (the exact residual window) into
-the same running softmax — masked per row by ``buf_len`` — and emits the
-normalized output, so no separate XLA combine pass runs after the kernel.
+Grid: ``(B, Hkv)``, one step per (batch row, kv head).  Its body walks the
+row's LIVE store blocks only, ``PP`` pages (one compute block) at a time,
+in a ``fori_loop`` of ``ceil(nb_valid[b] / PP)`` iterations: the work of a
+(row, head) follows its live KV, and one grid step's fixed cost is spread
+over all of its pages.  ``PP`` comes from the shapes alone
+(``pages_per_block``: 1,024 tokens' worth of pages, trimmed to a VMEM
+budget); the serving scheduler calls the same function for its
+``serve.attn.*`` block-fill counters.
 
-Per-row ``nb_valid``/``buf_len`` arrive as scalar-prefetch args (indexed by
-the batch grid axis before the body runs): every row of a continuous batch
-attends at its own position, the contract the serving scheduler relies on.
-Paged caches (DESIGN.md §10) add the per-row page table as a third
-scalar-prefetch operand: the store BlockSpec index maps resolve logical
-block ``n`` of row ``b`` to its physical arena page before the tile streams
-HBM→VMEM, so the kernel body decodes pooled storage completely unchanged.
+The store operands (payload and scale arrays) stay in HBM (``pl.ANY``):
+the body gathers each compute block's pages into a double-buffered VMEM
+stage with one async DMA per page and operand, the page ids read from the
+scalar-prefetched page table (dense caches use the block index itself).
+Block ``j + 1``'s copies are in flight while block ``j`` is decoded and
+attended.  Only live pages are copied — below ``nb_valid[b]`` and, paged,
+with a table entry ``>= 0`` (shard-local tables mark pages another shard
+hosts ``-1``, DESIGN.md §12); a dead page's stage slot is masked out of the
+scores and zeroed in V, so stale or uninitialized VMEM never reaches the
+output.  Inside a block each page is decoded by the layout's
+``decode_k``/``decode_v`` exactly as one page per step used to be, the
+decoded pages are stacked to ``[PP·T, D]``, and the flash-decoding running
+softmax ``(m, l, acc)`` takes one update per compute block over
+``[G, PP·T]`` scores.  After the loop the raw append buffer (the exact
+residual window) folds into the same running softmax — masked per row by
+``buf_len`` — and the normalized output is emitted, so no separate XLA
+combine pass runs after the kernel.
 
-Tiling rule (DESIGN.md §9): every operand's block spans whole trailing
-dims — ``q``/``out`` as ``[B, Hkv, G, D]`` (block ``(1, 1, G, D)``), store
-tiles as the layout's 2-D ``k_tile``/``v_tile``, the quantization units as
-``(1, D)`` / ``(1, T)`` rows — so Mosaic never sees a block dim that is
-neither a multiple of (8, 128) nor the full array dim, whatever G, D, T or
-the packed word count are.
+Per-row ``nb_valid``/``buf_len`` (and, paged, the ``[B, NB]`` page table)
+arrive as scalar-prefetch args: every row of a continuous batch attends at
+its own position, the contract the serving scheduler relies on.
+
+Tiling rule (DESIGN.md §9): every VMEM operand's block spans whole trailing
+dims — ``q``/``out``/buffers as ``[B, Hkv, G|T, D]`` blocks
+``(1, 1, G|T, D)``; each page DMA moves one whole store tile (the layout's
+2-D ``k_tile``/``v_tile``, or a ``(1, D)`` / ``(1, T)`` scale row) into a
+``(2, PP, *tile)`` stage — so Mosaic never sees a block dim that is neither
+a multiple of (8, 128) nor the full array dim, whatever G, D, T or the
+packed word count are.
 """
 
 from __future__ import annotations
@@ -56,6 +74,38 @@ from repro.kernels.runtime import resolve_interpret
 
 Array = jax.Array
 
+# A compute block covers this many tokens' pages (16 pages at T = 64) ...
+BLOCK_TOKENS = 1024
+# ... unless its VMEM (double-buffered stages + decoded f32 K/V) would pass
+# this budget, a quarter of the smallest default scoped-VMEM limit.
+BLOCK_VMEM_BYTES = 4 << 20
+
+
+def _vmem_tile_bytes(shape) -> int:
+    """Upper bound of the VMEM bytes one 2-D tile takes: 32-bit words,
+    rows padded to 8 sublanes and columns to 128 lanes."""
+    r, c = shape
+    return 4 * (-(-r // 8) * 8) * (-(-c // 128) * 128)
+
+
+def pages_per_block(nb: int, block_size: int, head_dim: int, tile) -> int:
+    """Pages ``PP`` one compute block of the kernel gathers and attends.
+
+    Derived from the shapes the kernel sees — ``nb`` store blocks per row,
+    ``block_size`` tokens per page, the layout's tiles — and nothing else:
+    ``BLOCK_TOKENS`` worth of pages, at most ``nb``, cut down until the
+    block's VMEM fits ``BLOCK_VMEM_BYTES``.
+    """
+    T, D = block_size, head_dim
+    stage = _vmem_tile_bytes(tile.k_tile) + _vmem_tile_bytes(tile.v_tile)
+    if tile.has_scales:
+        stage += _vmem_tile_bytes((1, 2 * D + 2 * T))  # the f32 scale row
+    per_page = 2 * stage + 2 * T * D * 4  # two stages + decoded f32 K and V
+    pp = max(1, min(nb, BLOCK_TOKENS // T))
+    while pp > 1 and pp * per_page > BLOCK_VMEM_BYTES:
+        pp -= 1
+    return pp
+
 
 def _kernel(
     nb_ref,        # scalar prefetch: i32 [B] per-row valid block counts
@@ -69,100 +119,125 @@ def _kernel(
     head_dim: int,
     scale: float,
     nb_total: int,
+    pages: int,
     paged: bool = False,
 ):
     pt_ref = None
     if paged:
-        # The page table rides as a third scalar-prefetch operand.  The
-        # BlockSpec index maps consume it to resolve logical block n to its
-        # arena page before the tile streams HBM→VMEM; the body reads it
-        # once more for the validity guard below (shard-local tables mark
-        # blocks this arena does not host with -1 — DESIGN.md §12 — and
-        # those steps must contribute nothing, not decode a clamped page).
-        pt_ref = refs[0]
-        refs = refs[1:]
-    # Per-layer aux operands (block-invariant, e.g. huffman's decode LUTs)
-    # sit between the buffers and the output; their VMEM-resident values
-    # are appended to every decode call — read inside the decode-step guard
-    # only, so skipped steps and the buffer-combine step never load them.
-    if n_aux:
-        aux_refs = refs[-(4 + n_aux):-4]
-        refs = refs[:-(4 + n_aux)] + refs[-4:]
-    else:
-        aux_refs = ()
-    if has_scales:
-        (q_ref, ks_ref, kmn_ref, kst_ref, vs_ref, vmn_ref, vst_ref,
-         kbuf_ref, vbuf_ref, out_ref, acc_s, m_s, l_s) = refs
-    else:
-        (q_ref, ks_ref, vs_ref, kbuf_ref, vbuf_ref,
-         out_ref, acc_s, m_s, l_s) = refs
-        kmn_ref = kst_ref = vmn_ref = vst_ref = None
+        # The page table rides as a third scalar-prefetch operand: the body
+        # reads page ids from it for its DMAs and its validity guard.
+        pt_ref, refs = refs[0], refs[1:]
+    n_st = 3 if has_scales else 2
+    q_ref, kbuf_ref, vbuf_ref = refs[:3]
+    aux_refs = refs[3:3 + n_aux]
+    stores = refs[3 + n_aux:3 + n_aux + n_st]          # HBM (pl.ANY)
+    out_ref = refs[3 + n_aux + n_st]
+    stages = refs[4 + n_aux + n_st:4 + n_aux + 2 * n_st]  # VMEM [2, PP, ...]
+    sem, acc_s, m_s, l_s = refs[4 + n_aux + 2 * n_st:]
     b = pl.program_id(0)
-    n = pl.program_id(2)
-    T = block_size
+    h = pl.program_id(1)
+    T, PP, D = block_size, pages, head_dim
+    nb = nb_ref[b]
 
-    @pl.when(n == 0)
-    def _init():
-        acc_s[...] = jnp.zeros_like(acc_s)
-        m_s[...] = jnp.full_like(m_s, NEG_INIT)
-        l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, NEG_INIT)
+    l_s[...] = jnp.zeros_like(l_s)
+    qg = q_ref[0, 0].astype(jnp.float32)  # [G, D]
 
-    # Store blocks: each batch row of a continuous batch has its own number
-    # of live blocks; steps past nb_valid[b] (and the final buffer step) skip.
-    # Paged shards additionally skip blocks whose table entry is unassigned
-    # (-1): their index map clamped to page 0, which holds some other row's
-    # data, so the step must not touch the running softmax.
-    live = n < nb_ref[b]
-    if paged:
-        live = live & (pt_ref[b, jnp.minimum(n, nb_total - 1)] >= 0)
+    def scores(k):  # [n, D] -> [G, n], on the MXU
+        return jax.lax.dot_general(qg, k, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32) * scale
 
-    @pl.when(live)
-    def _update():
-        aux = tuple(r[...] for r in aux_refs)
-        # --- decompress K in situ (VMEM), layout-owned decode ---
-        kd = decode_k(ks_ref[0, 0, 0],
-                      kmn_ref[0, 0, 0] if has_scales else None,
-                      kst_ref[0, 0, 0] if has_scales else None,
-                      *aux)  # [T, D]
-        # --- scores on the MXU ---
-        qg = q_ref[0, 0].astype(jnp.float32)  # [G, D]
-        s = jax.lax.dot_general(qg, kd, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        # --- flash-decoding running softmax ---
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])  # [G, T]
-        # --- decompress V in situ and accumulate ---
-        vd = decode_v(vs_ref[0, 0, 0],
-                      vmn_ref[0, 0, 0] if has_scales else None,
-                      vst_ref[0, 0, 0] if has_scales else None,
-                      *aux)  # [T, D]
-        acc_s[...] = acc_s[...] * alpha[:, None] + jax.lax.dot(
-            p, vd, preferred_element_type=jnp.float32)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1)
-        m_s[...] = m_new
-
-    # Final grid step: fold the raw buffer tail into the running softmax
-    # (masked per row by buf_len) and emit the normalized output.
-    @pl.when(n == nb_total)
-    def _buffer_and_emit():
-        qg = q_ref[0, 0].astype(jnp.float32)  # [G, D]
-        kb = kbuf_ref[0, 0].astype(jnp.float32)  # [T, D]
-        s = jax.lax.dot_general(qg, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        tpos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        ok = tpos < bl_ref[b]  # [1, T]
+    def fold(s, ok, v):
+        """One flash-decoding update of (m, l, acc): scores s [G, n], whose
+        columns outside ok [1, n] are dead, against values v [n, D]."""
         s = jnp.where(ok, s, NEG_INIT)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None]) * ok  # [G, T]
-        vb = vbuf_ref[0, 0].astype(jnp.float32)
-        acc = acc_s[...] * alpha[:, None] + jax.lax.dot(
-            p, vb, preferred_element_type=jnp.float32)
-        l = l_s[...] * alpha + jnp.sum(p, axis=1)
-        out_ref[0, 0] = acc / jnp.maximum(l, 1e-30)[:, None]
+        p = jnp.exp(s - m_new[:, None]) * ok
+        acc_s[...] = acc_s[...] * alpha[:, None] + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1)
+        m_s[...] = m_new
+
+    def page(j, i):
+        """(store index, live) of page i of compute block j of this row."""
+        n = j * PP + i
+        live = n < nb
+        if paged:
+            p = pt_ref[b, jnp.minimum(n, nb_total - 1)]
+            return (0, h, jnp.maximum(p, 0)), live & (p >= 0)
+        return (b, h, jnp.minimum(n, nb_total - 1)), live
+
+    def dma(j, slot, op):
+        """Start or wait (``op``) the copies of compute block j's live pages
+        into stage ``slot``: one per page and store operand."""
+        def one(i, carry):
+            idx, live = page(j, i)
+
+            @pl.when(live)
+            def _():
+                for src, dst in zip(stores, stages):
+                    getattr(pltpu.make_async_copy(
+                        src.at[idx], dst.at[slot, i], sem.at[slot]), op)()
+
+            return carry
+
+        jax.lax.fori_loop(0, PP, one, 0)
+
+    n_blocks = (nb + PP - 1) // PP
+
+    @pl.when(n_blocks > 0)
+    def _prefetch_first():
+        dma(0, 0, "start")
+
+    def block(j, carry):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_blocks)
+        def _prefetch_next():
+            dma(j + 1, 1 - slot, "start")
+
+        dma(j, slot, "wait")
+        lives = [page(j, i)[1] for i in range(PP)]
+
+        @pl.when(functools.reduce(jnp.logical_or, lives))
+        def _attend():
+            aux = tuple(r[...] for r in aux_refs)
+
+            def tiles(i, k):
+                """decode_k (k) / decode_v args of page i: tile, min, step."""
+                tile = stages[0 if k else 1][slot, i]
+                if not has_scales:
+                    return tile, None, None
+                sc = stages[2].at[slot, i]  # [1, W] f32: kmn|kst|vmn|vst
+                o, n = (0, D) if k else (2 * D, T)
+                return tile, sc[:, o:o + n], sc[:, o + n:o + 2 * n]
+
+            # Decompress the block's pages in situ (layout-owned decode per
+            # page); a dead page's columns are masked and its V rows zeroed,
+            # since its stage slot holds stale or uninitialized VMEM.
+            kd = jnp.concatenate(
+                [decode_k(*tiles(i, True), *aux) for i in range(PP)])
+            vd = jnp.concatenate(
+                [jnp.where(lives[i], decode_v(*tiles(i, False), *aux), 0.0)
+                 for i in range(PP)])  # [PP*T, D]
+            tpage = jax.lax.broadcasted_iota(jnp.int32, (1, PP * T), 1) // T
+            ok = functools.reduce(
+                jnp.logical_or, [(tpage == i) & lives[i] for i in range(PP)])
+            fold(scores(kd), ok, vd)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    # Fold the raw buffer tail (masked per row by buf_len) into the running
+    # softmax and emit the normalized output.
+    tpos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    fold(scores(kbuf_ref[0, 0].astype(jnp.float32)), tpos < bl_ref[b],
+         vbuf_ref[0, 0].astype(jnp.float32))
+    out_ref[0, 0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)[:, None]
 
 
 def fused_cache_attention_pallas(
@@ -183,21 +258,20 @@ def fused_cache_attention_pallas(
 
     With ``page_tab`` the stores are a shared paged arena (batch extent 1,
     ``P`` pages on the block axis — DESIGN.md §10): the table joins
-    ``nb_valid``/``buf_len`` as a scalar-prefetch operand and every store
-    BlockSpec index map resolves logical block ``n`` of row ``b`` to
-    ``page_tab[b, n]`` before the tile streams HBM→VMEM — the kernel body
-    (decode, flash softmax) is untouched by paging.  Unassigned entries
-    (-1) clamp to page 0 in the index map and their grid steps skip via
-    the body's validity guard — which also covers shard-local tables
-    (DESIGN.md §12) where blocks below ``nb_valid`` may be ``-1`` because
-    another shard hosts them.
+    ``nb_valid``/``buf_len`` as a scalar-prefetch operand and the body's
+    page DMAs read logical block ``n`` of row ``b`` from arena page
+    ``page_tab[b, n]`` — decode and flash softmax are untouched by paging.
+    Unassigned entries (-1) are neither copied nor attended — which also
+    covers shard-local tables (DESIGN.md §12) where blocks below
+    ``nb_valid`` may be ``-1`` because another shard hosts them.
+    ``nb_valid`` must not exceed the table's (or dense store's) ``NB``.
     """
     B, Hq, D = q.shape
     paged = page_tab is not None
     Hkv = k_store.shape[1]
     NB = page_tab.shape[1] if paged else k_store.shape[2]
-    P = k_store.shape[2]  # physical block extent (arena pages when paged)
     G, T = Hq // Hkv, block_size
+    PP = pages_per_block(NB, T, D, tile)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
 
@@ -205,58 +279,47 @@ def fused_cache_attention_pallas(
         _kernel,
         decode_k=tile.decode_k, decode_v=tile.decode_v,
         has_scales=tile.has_scales, n_aux=len(tile.aux),
-        block_size=T, head_dim=D, scale=scale, nb_total=NB, paged=paged,
+        block_size=T, head_dim=D, scale=scale, nb_total=NB, pages=PP,
+        paged=paged,
     )
-    grid = (B, Hkv, NB + 1)
 
-    # Index maps take the scalar-prefetch refs as trailing args; store tiles
-    # clamp to the last block on the buffer step (loaded but unused).  The
-    # paged variants get one extra trailing ref (the page table).
-    in_specs = []
-    inputs = []
-
-    def fixed_map(*idx):
-        return lambda b, h, n, *scalars: tuple(
-            b if i == "b" else h if i == "h" else i for i in idx)
-
-    in_specs.append(pl.BlockSpec((1, 1, G, D), fixed_map("b", "h", 0, 0)))
-    inputs.append(q.reshape(B, Hkv, G, D))
-
-    def add_store(arr, tile_shape):
-        if arr.shape[3:] != tuple(tile_shape):
-            raise ValueError(
-                f"store operand {arr.shape} does not end in its tile "
-                f"{tuple(tile_shape)}: a block must span whole trailing dims")
-        r = len(tile_shape)
-        if paged:
-            def imap(b, h, n, nb, bl, pt, r=r):
-                page = pt[b, jnp.minimum(n, NB - 1)]
-                return (0, h, jnp.clip(page, 0, P - 1)) + (0,) * r
-        else:
-            def imap(b, h, n, nb, bl, r=r):
-                return (b, h, jnp.minimum(n, NB - 1)) + (0,) * r
-        in_specs.append(pl.BlockSpec((1, 1, 1) + tuple(tile_shape), imap))
-        inputs.append(arr)
-
-    add_store(k_store, tile.k_tile)
-    if tile.has_scales:
-        add_store(k_min, (1, D))
-        add_store(k_step, (1, D))
-    add_store(v_store, tile.v_tile)
-    if tile.has_scales:
-        add_store(v_min, (1, T))
-        add_store(v_step, (1, T))
-    for buf in (k_buf, v_buf):
-        in_specs.append(pl.BlockSpec((1, 1, T, D), fixed_map("b", "h", 0, 0)))
-        inputs.append(buf)
+    row_head = lambda shape: pl.BlockSpec(shape, lambda b, h, *_: (b, h, 0, 0))
+    in_specs = [row_head((1, 1, G, D)), row_head((1, 1, T, D)),
+                row_head((1, 1, T, D))]
+    inputs = [q.reshape(B, Hkv, G, D), k_buf, v_buf]
     for a in tile.aux:
         # Per-layer aux operand (e.g. a codebook LUT): block-invariant, one
         # whole-array tile staged into VMEM with a constant index map.
         arr = jnp.asarray(a)
-        in_specs.append(pl.BlockSpec(arr.shape, fixed_map(*(0,) * arr.ndim)))
+        in_specs.append(pl.BlockSpec(
+            arr.shape, lambda b, h, *_, n=arr.ndim: (0,) * n))
         inputs.append(arr)
 
-    out_spec = pl.BlockSpec((1, 1, G, D), fixed_map("b", "h", 0, 0))
+    for arr, tile_shape in ((k_store, tile.k_tile), (v_store, tile.v_tile)):
+        if arr.shape[3:] != tuple(tile_shape):
+            raise ValueError(
+                f"store operand {arr.shape} does not end in its tile "
+                f"{tuple(tile_shape)}: a page DMA must move whole trailing dims")
+    stores = [k_store, v_store]
+    stages = [pltpu.VMEM((2, PP) + tuple(tile.k_tile), k_store.dtype),
+              pltpu.VMEM((2, PP) + tuple(tile.v_tile), v_store.dtype)]
+    if tile.has_scales:
+        # A page's four scale rows travel as ONE f32 row [kmn|kst|vmn|vst],
+        # lane-padded to whole 128-lane tiles: Mosaic cannot DMA a single
+        # bf16 row (two rows share a 32-bit sublane word), nor a row
+        # narrower than its lane tile, and one copy per page beats four.
+        # The bf16 -> f32 cast is exact: decode sees the same values.
+        W = -(-(2 * D + 2 * T) // 128) * 128
+        rows = jnp.concatenate(
+            [a.astype(jnp.float32) for a in (k_min, k_step, v_min, v_step)],
+            axis=-1)
+        pad = [(0, 0)] * (rows.ndim - 1) + [(0, W - 2 * D - 2 * T)]
+        stores.append(jnp.pad(rows, pad))
+        stages.append(pltpu.VMEM((2, PP, 1, W), jnp.float32))
+    # The stores stay in HBM; the body gathers their pages into the stages.
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(stores)
+    inputs += stores
+
     scalars = [
         jnp.broadcast_to(jnp.atleast_1d(nb_valid), (B,)).astype(jnp.int32),
         jnp.broadcast_to(jnp.atleast_1d(buf_len), (B,)).astype(jnp.int32),
@@ -267,10 +330,11 @@ def fused_cache_attention_pallas(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=grid,
+            grid=(B, Hkv),
             in_specs=in_specs,
-            out_specs=out_spec,
-            scratch_shapes=[
+            out_specs=row_head((1, 1, G, D)),
+            scratch_shapes=stages + [
+                pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((G, D), jnp.float32),
                 pltpu.VMEM((G,), jnp.float32),
                 pltpu.VMEM((G,), jnp.float32),

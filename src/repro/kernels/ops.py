@@ -154,8 +154,8 @@ def fused_cache_attention(
     its softmax combine); ``impl="xla"`` runs the vmapped oracle.  A
     ``page_tab`` (i32 [B, NB]) marks the stores as a shared paged arena
     (DESIGN.md §10): both impls gather K/V tiles through the table —
-    the kernel in its scalar-prefetch index maps, the oracle by an explicit
-    per-row gather.
+    the kernel by page DMAs from the scalar-prefetched table, the oracle
+    by an explicit per-row gather.
     """
     impl = resolve_impl(impl)
     D = q.shape[-1]

@@ -55,7 +55,7 @@ def fused_cache_attention_ref(
     oracle rather than a second implementation of the lazily-decoded paths.
     With ``page_tab`` the stores are a shared paged arena (DESIGN.md §10):
     each row's tiles are gathered through its page-table entries first —
-    the same indirection the kernel performs in its index maps — and
+    the same indirection the kernel's page DMAs perform — and
     unassigned slots clamp to page 0 under the ``nb_valid`` mask.  Slots
     whose table entry is unassigned (< 0) are additionally masked
     regardless of ``nb_valid`` — the shard-local table semantics of

@@ -109,9 +109,12 @@ def format_snapshot(stats: dict) -> str:
         per = " ".join(f"{k[:-2]}={v / st['steps'] * 1e3:.2f}"
                        for k, v in st.items()
                        if k.endswith("_s") and k != "wall_s")
+        fill = st["attn_live_blocks"] / max(st["attn_block_slots"], 1)
         lines.append(f"  step: steps={st['steps']} "
                      f"decode_steps={st['decode_steps']} "
-                     f"wall={st['wall_s']:.2f}s ms/step {per}")
+                     f"wall={st['wall_s']:.2f}s ms/step {per} "
+                     f"attn_blocks={st['attn_live_blocks']}"
+                     f"/{st['attn_block_slots']} (fill {fill:.2f})")
     if "trace" in stats:
         tr = stats["trace"]
         lines.append(f"  trace[{tr['level']}]: events={tr['events']} "

@@ -109,6 +109,23 @@ STEP_PHASES = (
 )
 
 
+def _fused_block_geometry(cfg: ModelConfig, specs) -> tuple | None:
+    """``(T, NB, PP)`` of the fused decode kernel serving the first cache
+    layer that resolves to it — block size, store blocks per row, pages per
+    compute block (``fused_kv_attn.pages_per_block``, the kernel's own
+    rule) — or None when every layer's decode attention runs as ``xla``."""
+    from repro.kernels import ops as kernel_ops
+    from repro.kernels.fused_kv_attn import pages_per_block
+
+    D = cfg.resolved_head_dim
+    for s in specs:
+        if kernel_ops.resolve_backend(s.attn_backend, s.impl) == "fused":
+            tile = s.impl.tile_decode(s, D)
+            return (s.block_size, s.n_blocks,
+                    pages_per_block(s.n_blocks, s.block_size, D, tile))
+    return None
+
+
 @dataclasses.dataclass
 class Request:
     prompt: np.ndarray  # int32 [S]
@@ -472,6 +489,11 @@ class Server:
         self._phase = Phases(self.metrics, "serve.step", STEP_PHASES)
         self._steps = self.metrics.counter("serve.step.steps")
         self._decode_steps = self.metrics.counter("serve.step.decode_steps")
+        # The fused decode kernel's compute-block fill (DESIGN.md §9): per
+        # decode dispatch, the live rows' live store blocks and the
+        # compute-block slots (whole blocks of PP pages) they take.
+        self._attn_live = self.metrics.counter("serve.attn.live_blocks")
+        self._attn_slots = self.metrics.counter("serve.attn.block_slots")
         # Chunked admission (DESIGN.md §13): PREFILLING rows by slot index.
         # A slot is busy while it appears in EITHER _slots or _prefill_tasks.
         self._prefill_tasks: dict[int, _PrefillTask] = {}
@@ -546,6 +568,7 @@ class Server:
             for d in range(self._n_data)]
         if mesh is not None:
             serve_shard.set_serve_mesh(mesh, self._inner_backend)
+        self._attn_geom = _fused_block_geometry(cfg, specs)
 
         if self.paged:
             # Size the shared arenas from the byte budget: one page = one
@@ -1723,6 +1746,13 @@ class Server:
             # so greedy tokens are merely delayed, never changed.
             return True
         with self._phase("decode_dispatch"):
+            if self._attn_geom is not None:
+                # The kernel attends the blocks flushed once this step's
+                # token is appended: min((pos + 1) // T, NB) per row.
+                T, NB, PP = self._attn_geom
+                live = np.minimum((self._pos[rows] + 1) // T, NB)
+                self._attn_live.inc(int(live.sum()))
+                self._attn_slots.inc(int((-(-live // PP) * PP).sum()))
             toks, self.state = self._decode(
                 self.params, jnp.asarray(self._cur), jnp.asarray(self._pos),
                 self.state)
@@ -1835,8 +1865,12 @@ class Server:
             },
             # Host time of the step by phase (STEP_PHASES; self seconds,
             # summing to wall_s) and the steps that dispatched a decode.
+            # attn_*: the fused kernel's live blocks and compute-block
+            # slots over the decode steps (0 where attention runs as xla).
             "step": {"steps": self._steps.value,
                      "decode_steps": self._decode_steps.value,
+                     "attn_live_blocks": self._attn_live.value,
+                     "attn_block_slots": self._attn_slots.value,
                      **self._phase.snapshot()},
             # Terminal-state accounting (DESIGN.md §15): how many requests
             # ever entered, and every abnormal way one left.

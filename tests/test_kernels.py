@@ -9,11 +9,17 @@ import pytest
 
 from repro.core import cache as C
 from repro.core import huffman
-from repro.kernels import ops, ref
+from repro.kernels import fused_kv_attn, ops, ref
 from repro.kernels.fused_kv_attn import fused_cache_attention_pallas
 from repro.kernels.huffman_decode import (huffman_attn_scores_pallas,
                                           huffman_decode_pallas)
 from repro.kernels.pack_encode import quant_pack_pallas
+
+
+# Jitted oracle and prefill: one compile instead of op-by-op dispatch.
+_ref_jit = jax.jit(ref.fused_cache_attention_ref,
+                   static_argnames=("tile", "block_size", "scale"))
+_prefill_jit = jax.jit(C.prefill, static_argnums=0)
 
 
 def _kernel_args(c):
@@ -28,6 +34,9 @@ def _kernel_args(c):
     (2, 2, 3, 96, 32, 16),
     (1, 4, 2, 64, 64, 16),    # MXU-ish head_dim
     (2, 1, 8, 48, 24, 8),     # odd head_dim
+    # 16-page compute blocks over 34 store blocks (not a multiple of 16);
+    # 17 live blocks end one page into the second compute block
+    (2, 1, 2, 1100, 16, 64),
 ])
 def test_fused_cache_attention_sweep(B, Hkv, G, S, D, T, layout, rng):
     """Kernel (buffer tail folded in) vs the vmapped tile-decode oracle,
@@ -37,11 +46,45 @@ def test_fused_cache_attention_sweep(B, Hkv, G, S, D, T, layout, rng):
     k = jnp.asarray(rng.normal(size=(B, Hkv, S, D)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(B, Hkv, S, D)).astype(np.float32))
     q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)).astype(np.float32))
-    c = C.prefill(spec, k, v)
+    c = _prefill_jit(spec, k, v)
     tile = spec.impl.tile_decode(spec, D)
     kw = dict(tile=tile, block_size=T)
-    out_r = ref.fused_cache_attention_ref(q, *_kernel_args(c), **kw)
+    out_r = _ref_jit(q, *_kernel_args(c), **kw)
     out_p = fused_cache_attention_pallas(q, *_kernel_args(c), **kw)
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("layout", ["packed", "raw", "kivi", "huffman"])
+def test_fused_paged_compute_blocks(layout, rng):
+    """Paged page gather vs the oracle on the tables serving produces, with
+    16-page compute blocks over 20 store blocks: row 0 has no live block,
+    row 1's live blocks end mid compute block, row 2's shard-local table
+    holds -1 inside its live range, row 3 shares row 1's first five arena
+    pages (prefix sharing)."""
+    from repro.core import pool
+
+    T, D, S = 64, 16, 1240
+    spec = C.CacheSpec(layout=layout, block_size=T, max_seq=20 * T,
+                       rel_scale_k=0.05, rel_scale_v=0.15)
+    k = jnp.asarray(rng.normal(size=(4, 1, S, D)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(4, 1, S, D)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(4, 2, D)).astype(np.float32))
+    NB = spec.n_blocks
+    tile = spec.impl.tile_decode(spec, D)
+    assert NB == 20 and fused_kv_attn.pages_per_block(NB, T, D, tile) == 16
+    pages = rng.permutation(4 * NB + 5)[:4 * NB].reshape(4, NB)
+    c = jax.jit(pool.from_dense, static_argnums=1)(
+        _prefill_jit(spec, k, v), 4 * NB + 5, pages)
+    tab = np.asarray(c.page_tab).copy()
+    tab[2, 1::3] = -1
+    tab[3, :5] = tab[1, :5]
+    args = list(_kernel_args(c))
+    args[8] = jnp.asarray([0, 18, S // T, 18], jnp.int32)
+    args += [jnp.asarray(tab)]
+    kw = dict(tile=tile, block_size=T)
+    out_r = _ref_jit(q, *args, **kw)
+    out_p = fused_cache_attention_pallas(q, *args, **kw)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=1e-4, rtol=1e-4)
 

@@ -229,7 +229,8 @@ def key_tree(d):
 
 
 LAT_KEYS = {"count", "sum", "mean", "min", "max", "p50", "p99"}
-STEP_KEYS = ({"steps", "decode_steps", "wall_s"}
+STEP_KEYS = ({"steps", "decode_steps", "wall_s", "attn_live_blocks",
+              "attn_block_slots"}
              | {f"{p}_s" for p in STEP_PHASES})
 
 
@@ -407,8 +408,38 @@ def test_format_snapshot_renders_all_sections(traced):
     server, _, _ = traced
     text = obs.format_snapshot(server.stats())
     for frag in ("serve[paged]", "lifecycle:", "prefill[", "latency:",
-                 "pool:", "shards:", "prefix[on]", "step:", "trace[events]"):
+                 "pool:", "shards:", "prefix[on]", "step:", "attn_blocks=",
+                 "trace[events]"):
         assert frag in text, frag
+
+
+@pytest.mark.parametrize("backend", ["fused", "xla"])
+def test_attn_block_counters(setup, backend):
+    """serve.attn.live_blocks / block_slots against hand counts: two rows
+    (prompts 14 and 21, block 8, 4 blocks per row, so 4 pages per compute
+    block) decode at positions 14-17 and 21-23; the kernel attends
+    min((pos + 1) // 8, 4) blocks per row: 1+2+2+2 and 2+2+3, each rounded
+    up to one 4-page compute block.  Through xla both stay 0."""
+    cfg, params, _ = setup
+    rng = np.random.default_rng(5)
+    server = Server(cfg, params, ServerConfig(
+        max_slots=2, max_seq=32, cache_mode="paged", attn_backend=backend),
+        q_chunk=32, kv_chunk=32)
+    for L, n in ((14, 5), (21, 4)):
+        server.submit(Request(
+            prompt=rng.integers(0, cfg.vocab_size, L).astype(np.int32),
+            max_new_tokens=n))
+    server.run()
+    st = server.stats()["step"]
+    snap = server.metrics.snapshot()["serve"]["attn"]
+    if backend == "xla":
+        assert st["attn_live_blocks"] == st["attn_block_slots"] == 0
+        return
+    assert server._attn_geom == (8, 4, 4)
+    assert st["attn_live_blocks"] == snap["live_blocks"] == 7 + 7
+    assert st["attn_block_slots"] == snap["block_slots"] == 4 * 4 + 3 * 4
+    assert st["attn_live_blocks"] <= st["attn_block_slots"]
+    assert "kvcomp_serve_attn_live_blocks 14" in server.metrics.prometheus_text()
 
 
 # The Server's jitted device entry points — everything a step can dispatch.

@@ -93,6 +93,29 @@ def test_fused_kernel_compiles(layout, mode, one_chip, no_compile_cache):
         *a, tile=tile, block_size=T, interpret=False), *args)
 
 
+@pytest.mark.parametrize("B,HKV_,G,NB,P", [
+    (64, 4, 8, 64, 7825),    # yi_6b.longdoc: 4k context, 64 slots
+    (32, 8, 4, 512, 3756),   # ministral_8b.longdoc: 32k context, 32 slots
+], ids=["yi_6b", "ministral_8b"])
+def test_fused_kernel_compiles_at_cell_shapes(B, HKV_, G, NB, P, one_chip,
+                                              no_compile_cache):
+    """The paged packed kernel at the benchmark cells' own batch, heads,
+    context and arena: its page stages and compute blocks must fit VMEM."""
+    spec = C.CacheSpec(layout="packed", block_size=T, max_seq=NB * T,
+                       mode="paged", pool_pages=P)
+    cache = _abstract(jax.eval_shape(
+        lambda: C.init_layer_cache(spec, B, HKV_, D)), one_chip)
+    args = [jax.ShapeDtypeStruct((B, HKV_ * G, D), jnp.bfloat16,
+                                 sharding=one_chip),
+            cache.k_store, cache.k_min, cache.k_step,
+            cache.v_store, cache.v_min, cache.v_step,
+            cache.k_buf, cache.v_buf, cache.n_flushed, cache.buf_len,
+            cache.page_tab]
+    tile = spec.impl.tile_decode(spec, D)
+    _compile(lambda *a: fused_cache_attention_pallas(
+        *a, tile=tile, block_size=T, interpret=False), *args)
+
+
 @pytest.mark.parametrize("token_wise,bits", [(False, 5), (True, 3), (False, 2)])
 def test_quant_pack_compiles(token_wise, bits, one_chip, no_compile_cache):
     x = jax.ShapeDtypeStruct((B * HKV * 8, T, D), jnp.bfloat16, sharding=one_chip)
