@@ -41,6 +41,7 @@ def main(argv=None) -> int:
 
     c = spec.load_cell(args.workload)
     n_pick = check.check_params(c)["requests"]
+    ref = c.architecture.logits_at
     for seed in args.seeds:
         t0 = time.monotonic()
         runner = cell_mod.Runner(c, seed, args.seconds, False, t0)
@@ -48,7 +49,7 @@ def main(argv=None) -> int:
         runner.free()
         picks = check.sample(runner.records, n_pick, seed)
         kv = check.kv_of(c.config)
-        g = check.served_gaps(runner.params, runner.arch, picks, kv)
+        g = check.served_gaps(ref, runner.params, runner.arch, picks, kv)
         out = {"seed": seed, "program_mean": float(g.mean()),
                "program_max": float(g.max()), "tokens": int(g.size),
                "failed": sum(r.result.finish_reason == "error"
@@ -58,8 +59,8 @@ def main(argv=None) -> int:
                "setup_s": runner.setup_s}
         if seed in args.control_seeds:
             for prec in args.controls:
-                cg = check.control_gaps(runner.params, runner.arch, picks,
-                                        prec, kv)
+                cg = check.control_gaps(ref, runner.params, runner.arch,
+                                        picks, prec, kv)
                 out[f"{prec}_mean"] = float(cg.mean())
                 out[f"{prec}_max"] = float(cg.max())
         out["seconds"] = time.monotonic() - t0

@@ -6,6 +6,8 @@ returns, inside ``kvbench.cell``.  Layout of the benchmark directory:
 
 * ``bench/run.py``                  -- entry point: one cell, one run
 * ``bench/configs/<config>.json``   -- model sizes as run, source, cut
+* ``bench/archs/<arch>.py``         -- one module per architecture: sizes,
+                                       weights, reference, counts
 * ``bench/traffic/<traffic>.json``  -- traffic-mix parameters (data only)
 * ``bench/metrics/<metric>.py``     -- one reader per per-layer metric
 * ``bench/peaks.json``              -- chip peaks keyed by ``device_kind``

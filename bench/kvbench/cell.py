@@ -3,6 +3,9 @@
 The window drives the program's normal serving path: ``repro.api.serve``
 -> ``Server.submit`` / ``Server.step`` with the cell's configuration, paged
 compressed pool, prefix cache and chunked prefill, backend ``auto``.
+What belongs to one architecture -- its sizes, weights, the program's
+``ModelConfig``, its operation counts and the cache's bytes -- comes from
+the cell's architecture module (``spec.load_arch``).
 """
 
 from __future__ import annotations
@@ -19,7 +22,7 @@ import time
 import jax
 import numpy as np
 
-from kvbench import check, model, spec, stats, traffic as traffic_mod
+from kvbench import check, spec, stats, traffic as traffic_mod
 
 
 @dataclasses.dataclass(eq=False)
@@ -36,7 +39,7 @@ class Record:
 class Context:
     """What a per-layer metric reader may read."""
     cell: spec.Cell
-    arch: model.Arch
+    arch: object         # the cell's ``architecture.Arch``
     chips: int
     peaks: dict
     t_open: float
@@ -99,24 +102,7 @@ def memory_peak(chips: int) -> int:
     return int(max(peaks))
 
 
-def program_config(config: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    a = model.Arch.from_config(config)
-    s = config["serving"]
-    return ModelConfig(
-        name=config["name"], family="dense", n_layers=a.n_layers,
-        d_model=a.d_model, vocab_size=a.vocab, n_heads=a.n_heads,
-        n_kv_heads=a.n_kv_heads, head_dim=a.head_dim, d_ff=a.d_ff,
-        rope_theta=a.rope_theta, norm_eps=a.norm_eps,
-        sliding_window=a.window, cache_layout=s["cache_layout"],
-        cache_block=s["cache_block"], rel_scale_k=s["rel_scale_k"],
-        rel_scale_v=s["rel_scale_v"], cache_mode=s["cache_mode"],
-        attn_backend=s["attn_backend"], dtype=config["model"]["torch_dtype"])
-
-
-def make_server(config: dict, params):
+def make_server(config: dict, params, architecture):
     from repro import api
 
     s = config["serving"]
@@ -124,28 +110,13 @@ def make_server(config: dict, params):
     if s.get("mesh"):
         from repro.launch.mesh import make_serve_mesh
         mesh = make_serve_mesh(s["mesh"])
-    return api.serve(program_config(config), params,
+    return api.serve(architecture.program_config(config), params,
                      max_slots=s["max_slots"], max_seq=s["max_seq"],
                      cache_mode=s["cache_mode"],
                      pool_hbm_bytes=s["pool_hbm_bytes"],
                      prefix_cache=s["prefix_cache"],
                      prefill_chunk_tokens=s.get("prefill_chunk_tokens"),
                      mesh=mesh)
-
-
-def arena_bytes(server, config: dict) -> dict:
-    """Bytes per cached token over all served layers, from the arena's
-    array shapes: the compressed store (payload and scales) per page slot,
-    and the raw append buffer per token."""
-    kv = server.state["kv"]
-    T = config["serving"]["cache_block"]
-    store = sum(getattr(kv, f).nbytes for f in
-                ("k_store", "k_min", "k_step", "v_store", "v_min", "v_step"))
-    pages = kv.k_store.shape[-3]
-    raw = kv.k_buf.nbytes + kv.v_buf.nbytes
-    slots = config["serving"]["max_slots"]
-    return {"store_bytes_per_token": store / (pages * T),
-            "raw_bytes_per_token": raw / (slots * T), "block": T}
 
 
 class CompileCounter:
@@ -208,7 +179,7 @@ class Runner:
         self.device = device_info(cell.chips, require_tpu)
         self.peaks = (peaks if peaks is not None else
                       spec.load_peaks(self.device["kind"], cell.bench_dir))
-        self.arch = model.Arch.from_config(cell.config)
+        self.arch = cell.architecture.Arch.from_config(cell.config)
         self.compiles = CompileCounter()
         self.records: list[Record] = []
 
@@ -219,16 +190,16 @@ class Runner:
         self._t_phase = now
 
     def setup(self):
-        cfg = self.cell.config
+        cfg, architecture = self.cell.config, self.cell.architecture
         self.phases, self._t_phase = [], self.t_start
         self.traffic = traffic_mod.generate(self.cell.traffic, self.arch.vocab,
                                             self.seed, self.seconds)
         self._phase("start and traffic")
         self.params = jax.block_until_ready(
-            model.make_weights(self.arch, self.seed))
+            architecture.make_weights(self.arch, self.seed))
         self._phase("weights")
-        self.server = make_server(cfg, self.params)
-        self.kv = arena_bytes(self.server, cfg)
+        self.server = make_server(cfg, self.params, architecture)
+        self.kv = architecture.arena_bytes(self.server, cfg)
         self._phase("server")
         from repro import api
 

@@ -2,12 +2,12 @@
 
 After the window, a sample of the requests it served -- drawn from the
 seed, the one with the longest sequence always in it -- is run through the
-plain float32 reference (``kvbench.reference``) once per request, over its
-prompt and its served tokens, with the KV cache the configuration states
-(keys and values of complete blocks read back through its error-bounded
-quantiser, the rest exact).  At every served position the gap is the
-reference's best logit less the reference logit of the served token: 0
-where the server picked the reference's argmax.
+plain float32 reference (the architecture's ``logits_at``) once per
+request, over its prompt and its served tokens, with the KV cache the
+configuration states (keys and values of complete blocks read back through
+its error-bounded quantiser, the rest exact).  At every served position
+the gap is the reference's best logit less the reference logit of the
+served token: 0 where the server picked the reference's argmax.
 
 The number compared is the mean gap over all served tokens of the sample.
 The widest gap was the first choice, and it does not separate: sound runs
@@ -31,8 +31,6 @@ import json
 
 import jax.numpy as jnp
 import numpy as np
-
-from kvbench import reference
 
 
 @dataclasses.dataclass
@@ -93,25 +91,26 @@ def kv_of(config: dict) -> tuple | None:
     return s["cache_block"], s["rel_scale_k"], s["rel_scale_v"]
 
 
-def served_gaps(params, arch, picks, kv) -> np.ndarray:
-    """The reference gap of every served token of ``picks``."""
+def served_gaps(logits_at, params, arch, picks, kv) -> np.ndarray:
+    """The reference gap of every served token of ``picks``;
+    ``logits_at`` is the architecture's reference."""
     out = [np.zeros(0)]
     for rec in picks:
         seq, at, toks = served_positions(rec)
-        out.append(gaps(reference.logits_at(params, arch, seq, at, kv=kv),
-                        toks))
+        out.append(gaps(logits_at(params, arch, seq, at, kv=kv), toks))
     return np.concatenate(out)
 
 
-def control_gaps(params, arch, picks, precision: str, kv) -> np.ndarray:
+def control_gaps(logits_at, params, arch, picks, precision: str,
+                 kv) -> np.ndarray:
     """The control: the reference computed in ``precision`` put in the
     program's place.  At every served position of ``picks``, the reference
     gap of the token the lower precision ranks first."""
     out = [np.zeros(0)]
     for rec in picks:
         seq, at, _ = served_positions(rec)
-        ref = reference.logits_at(params, arch, seq, at, kv=kv)
-        low = reference.logits_at(params, arch, seq, at, precision, kv=kv)
+        ref = logits_at(params, arch, seq, at, kv=kv)
+        low = logits_at(params, arch, seq, at, precision, kv=kv)
         out.append(gaps(ref, np.asarray(jnp.argmax(low, axis=-1))))
     return np.concatenate(out)
 
@@ -119,7 +118,8 @@ def control_gaps(params, arch, picks, precision: str, kv) -> np.ndarray:
 def check(cell, arch, params, records, seed: int, failed: int) -> Verdict:
     p = check_params(cell)
     picks = sample(records, p["requests"], seed)
-    g = served_gaps(params, arch, picks, kv_of(cell.config))
+    g = served_gaps(cell.architecture.logits_at, params, arch, picks,
+                    kv_of(cell.config))
     mean = float(g.mean()) if g.size else None
     numbers = {
         "mean_logit_gap": {"value": mean, "limit": p["mean_logit_gap"]},

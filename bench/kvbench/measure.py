@@ -2,20 +2,14 @@
 the window, the roofline of the fused decode attention kernel, and the
 device time of the program's jitted closures.
 
-The kernel's byte model is that of ``repro.launch.dryrun.decode_kv_traffic``
-made exact per row: a decode step's attention reads, for each live row and
-layer, the compressed store of its flushed blocks (payload and scales, at
-the bytes per token the arena's shapes give), the raw append buffer of its
-tail, its queries and its output.  A dead grid step reads nothing that
-counts.  Its operations are a score and a weighted sum per (query head,
-cached token).  Neither depends on how the kernel is written.
+What one token, one prefilled chunk or one decode step's attention costs
+is the architecture's to count (``bench/archs/<name>.py``: ``token_flops``,
+``prefill_flops``, ``decode_attn_work``); this module sums those counts
+over what the window served.  None of them depends on how the kernel is
+written.
 """
 
 from __future__ import annotations
-
-import numpy as np
-
-from kvbench.model import token_flops
 
 # Names the program's jitted serving closures carry in a device trace
 # (``jax.jit`` of ``Server._decode`` and of the paged chunked-prefill
@@ -27,19 +21,16 @@ CHUNK_PROGRAMS = ("jit__chunk_paged(", "jit__chunk_paged_scan(",
 
 def window_flops(ctx) -> float:
     """Model operations the window did: every decoded token at its context,
-    every prefilled prompt token (the output head runs once per chunk) and
-    the prefill attention pairs the program counted."""
-    a = ctx.arch
-    dec = ctx.decode_contexts()
-    flops = float(sum(token_flops(a, int(c)) for c in dec))
-    head = 2 * a.d_model * a.vocab
-    pf_tokens = ctx.counter("prefill", "prefill_tokens")
-    chunks = ctx.counter("prefill", "chunks")
-    flops += pf_tokens * (2 * a.matmul_params() - head) + chunks * head
-    if "prefix" in ctx.stats_close:
-        flops += (ctx.counter("prefix", "prefill_attn_pairs")
-                  * a.attn_flops_per_pair())
-    return flops
+    every prefilled prompt token and the prefill attention pairs the
+    program counted."""
+    model, a = ctx.cell.architecture, ctx.arch
+    flops = float(sum(model.token_flops(a, int(c))
+                      for c in ctx.decode_contexts()))
+    pairs = (ctx.counter("prefix", "prefill_attn_pairs")
+             if "prefix" in ctx.stats_close else 0)
+    return flops + model.prefill_flops(
+        a, ctx.counter("prefill", "prefill_tokens"),
+        ctx.counter("prefill", "chunks"), pairs)
 
 
 def step_mfu(ctx) -> float:
@@ -51,18 +42,8 @@ def step_mfu(ctx) -> float:
 def decode_attn_work(ctx) -> tuple[float, float]:
     """(operations, bytes) the decode steps' attention needed over the
     window, all layers."""
-    a, kv = ctx.arch, ctx.kv
-    T = kv["block"]
-    c = ctx.decode_contexts().astype(np.float64)
-    flushed = np.floor(c / T) * T
-    per_layer_bytes = (flushed * kv["store_bytes_per_token"]
-                       + (c - flushed) * kv["raw_bytes_per_token"]
-                       ) / a.n_layers
-    # queries read (bf16) and outputs written (f32), per row and layer
-    io = a.n_heads * a.head_dim * (2 + 4)
-    nbytes = float(np.sum(per_layer_bytes + io)) * a.n_layers
-    flops = float(np.sum(c)) * a.attn_flops_per_pair()
-    return flops, nbytes
+    return ctx.cell.architecture.decode_attn_work(
+        ctx.arch, ctx.decode_contexts(), ctx.kv)
 
 
 def kernel_seconds(ctx, program: str) -> float | None:

@@ -1,35 +1,29 @@
-"""Plain reference of the served model: a Llama-style dense GQA decoder in
-float32 ``jax.numpy`` with no cache, no kernels and no batching.
+"""Shared blocks of the plain references: float32 ``jax.numpy`` with no
+cache, no kernels and no batching.  Each architecture in ``bench/archs/``
+builds its reference (``logits_at``) from these; none imports the program.
 
-Per layer: RMSNorm, Q/K/V projections, rotary embedding on (first half,
-second half) pairs with ``theta ** (-i / (D/2))`` frequencies, causal
-softmax attention (within ``sliding_window`` when the configuration has
-one), output projection and residual; RMSNorm, SwiGLU MLP and residual.
-Then the final RMSNorm and the output head.  It imports nothing of the
-program.
-
-It runs one sequence at a time, layer by layer, the attention in blocks of
-query rows and the MLP in blocks of tokens, so that a 32k-token sequence
-fits beside the weights on one chip.  Every product runs at
-``Precision.HIGHEST`` (on a TPU a float32 product is otherwise computed in
-bfloat16 passes).
+``_rms`` is RMSNorm; ``_rope`` the rotary embedding on (first half, second
+half) pairs with ``theta ** (-i / (D/2))`` frequencies; ``_attention``
+causal softmax attention (within a window when one is given) in blocks of
+query rows, reading the configuration's quantised KV cache when ``kv`` is
+given (``_kv_cache``, ``_quantize``).  A reference runs one sequence at a
+time, layer by layer, padded to a multiple of ``PAD`` tokens, so that a
+32k-token sequence fits beside the weights on one chip.  Every product runs
+at ``Precision.HIGHEST`` (on a TPU a float32 product is otherwise computed
+in bfloat16 passes).
 
 ``precision`` selects the control of the correctness check instead:
-``"int8"`` quantises both operands of every projection, MLP and head
-product to symmetric int8 (per token for activations, per output channel
-for weights) before the product; ``"fp8"`` rounds them to float8 e4m3
-under the same scales.
+``"int8"`` quantises both operands of every product ``_linear`` makes to
+symmetric int8 (per token for activations, per output channel for weights)
+before the product; ``"fp8"`` rounds them to float8 e4m3 under the same
+scales.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from kvbench.model import Arch
 
 HIGHEST = jax.lax.Precision.HIGHEST
 PRECISIONS = ("f32", "int8", "fp8")
@@ -131,62 +125,3 @@ def _attention(q, k, v, window, kv):
 
     out = jax.lax.map(block, (qb, jnp.arange(S // nq)))
     return out.reshape(S, H, D)
-
-
-@functools.partial(jax.jit, static_argnames=("arch", "precision", "kv"))
-def _layer(x, blocks, layer, arch: Arch, precision: str, kv):
-    p = jax.tree.map(lambda a: a[layer], blocks)
-    S, d = x.shape
-    H, Hkv, D = arch.n_heads, arch.n_kv_heads, arch.head_dim
-    pos = jnp.arange(S)
-    h = _rms(x, p["ln_attn"], arch.norm_eps)
-    a = p["attn"]
-    q = _linear(h, a["wq"].reshape(d, H * D), precision).reshape(S, H, D)
-    k = _linear(h, a["wk"].reshape(d, Hkv * D), precision).reshape(S, Hkv, D)
-    v = _linear(h, a["wv"].reshape(d, Hkv * D), precision).reshape(S, Hkv, D)
-    q = _rope(q, pos, arch.rope_theta)
-    k = _rope(k, pos, arch.rope_theta)
-    o = _attention(q, k, v, arch.window, kv).reshape(S, H * D)
-    x = x + _linear(o, a["wo"].reshape(H * D, d), precision)
-
-    m = p["mlp"]
-
-    def mlp(xc):
-        hc = _rms(xc, p["ln_mlp"], arch.norm_eps)
-        g = _linear(hc, m["w_gate"], precision)
-        u = _linear(hc, m["w_up"], precision)
-        return xc + _linear(jax.nn.silu(g) * u, m["w_down"], precision)
-
-    return jax.lax.map(mlp, x.reshape(S // PAD, PAD, d)).reshape(S, d)
-
-
-@functools.partial(jax.jit, static_argnames=("arch", "precision"))
-def _head(x, at, params, arch: Arch, precision: str):
-    h = _rms(x[at], params["ln_f"], arch.norm_eps)
-    return _linear(h, params["emb"]["unembed"], precision)
-
-
-@functools.partial(jax.jit, static_argnames=("arch",))
-def _embed(tokens, params, arch: Arch):
-    return params["emb"]["embed"][tokens].astype(jnp.float32)
-
-
-def logits_at(params, arch: Arch, tokens: np.ndarray, at: np.ndarray,
-              precision: str = "f32", kv: tuple | None = None) -> jax.Array:
-    """Float32 logits [len(at), vocab] of ``tokens`` at positions ``at``.
-    ``kv`` = ``(block, rel_scale_k, rel_scale_v)`` applies the
-    configuration's quantised KV cache (None: exact keys and values)."""
-    if precision not in PRECISIONS:
-        raise ValueError(f"precision must be one of {PRECISIONS}")
-    S = len(tokens)
-    padded = -(-S // PAD) * PAD
-    buf = np.zeros(padded, np.int32)
-    buf[:S] = tokens
-    x = _embed(jnp.asarray(buf), params, arch)
-    for layer in range(arch.n_layers):
-        x = _layer(x, params["blocks"], jnp.int32(layer), arch, precision,
-                   kv)
-    n = len(at)
-    at_buf = np.zeros(-(-n // AT_PAD) * AT_PAD, np.int32)
-    at_buf[:n] = at
-    return _head(x, jnp.asarray(at_buf), params, arch, precision)[:n]

@@ -93,10 +93,11 @@ def test_lower_precision_control_is_not_correct(bench):
         r.collect()
         r.free()
         picks = check.sample(r.records, 3, seed)
-        sound = check.served_gaps(r.params, r.arch, picks, kv).mean()
+        ref = c.architecture.logits_at
+        sound = check.served_gaps(ref, r.params, r.arch, picks, kv).mean()
         assert sound <= TINY_LIMIT
-        gaps.append(float(check.control_gaps(r.params, r.arch, picks, "fp8",
-                                             kv).mean()))
+        gaps.append(float(check.control_gaps(ref, r.params, r.arch, picks,
+                                             "fp8", kv).mean()))
     assert min(gaps) > TINY_LIMIT, gaps
 
 

@@ -15,9 +15,10 @@ import pytest
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 import tinycell  # noqa: E402
 
-from kvbench import measure, model, spec, stats, traffic  # noqa: E402
+from kvbench import measure, spec, stats, traffic  # noqa: E402
 
 MIXES = ("longdoc_4k", "longdoc_32k", "chat")
+LLAMA = spec.load_arch({"architecture": "llama_gqa"})
 
 
 def _mix(name):
@@ -135,7 +136,7 @@ def test_unknown_device_kind_is_an_error():
         spec.load_peaks("cpu")
 
 
-YI = model.Arch(n_layers=8, d_model=4096, n_heads=32, n_kv_heads=4,
+YI = LLAMA.Arch(n_layers=8, d_model=4096, n_heads=32, n_kv_heads=4,
                 head_dim=128, d_ff=11008, vocab=64000, rope_theta=5e6,
                 norm_eps=1e-5, window=None)
 
@@ -148,6 +149,8 @@ class _Ctx:
         class R:
             pass
         self.arch, self.chips, self.t_open, self.t_close = YI, 1, 10.0, 11.0
+        self.cell = R()
+        self.cell.architecture = LLAMA
         self.peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
         self.kv = {"store_bytes_per_token": 656.0 * 8,
                    "raw_bytes_per_token": 2048.0 * 8, "block": 64}
@@ -177,7 +180,7 @@ def test_model_flops_by_hand():
     # per layer: 4096*(32+8)*128 + 32*128*4096 + 3*4096*11008 = 173,015,040
     assert YI.matmul_params() == 8 * 173_015_040 + 4096 * 64000
     assert YI.attn_flops_per_pair() == 4 * 32 * 128 * 8
-    assert model.token_flops(YI, 10) == 2 * YI.matmul_params() + 10 * 131072
+    assert LLAMA.token_flops(YI, 10) == 2 * YI.matmul_params() + 10 * 131072
 
 
 def test_mfu_and_roofline_by_hand():
@@ -185,7 +188,7 @@ def test_mfu_and_roofline_by_hand():
     # tokens 1 and 2 of both requests are decoded in the window at
     # contexts 3001 and 3002
     assert sorted(ctx.decode_contexts().tolist()) == [3001, 3001, 3002, 3002]
-    dec = 2 * (model.token_flops(YI, 3001) + model.token_flops(YI, 3002))
+    dec = 2 * (LLAMA.token_flops(YI, 3001) + LLAMA.token_flops(YI, 3002))
     head = 2 * 4096 * 64000
     pf = 64 * (2 * YI.matmul_params() - head) + head
     assert measure.window_flops(ctx) == pytest.approx(dec + pf)
@@ -218,8 +221,6 @@ def test_reference_matches_the_program_forward():
 
     import jax.numpy as jnp
 
-    from kvbench import reference
-    from kvbench.cell import program_config
     from repro.models import model as M
 
     cfg = json.loads((tinycell.BENCH / "configs" / "yi_6b.json").read_text())
@@ -227,21 +228,21 @@ def test_reference_matches_the_program_forward():
                         num_attention_heads=4, num_key_value_heads=2,
                         num_hidden_layers=2, vocab_size=256, head_dim=16,
                         rope_theta=10000.0)
-    arch = model.Arch.from_config(cfg)
-    params = model.make_weights(arch, 7, dtype=jnp.float32)
-    pcfg = dataclasses.replace(program_config(cfg), dtype="float32")
+    arch = LLAMA.Arch.from_config(cfg)
+    params = LLAMA.make_weights(arch, 7, dtype=jnp.float32)
+    pcfg = dataclasses.replace(LLAMA.program_config(cfg), dtype="float32")
     toks = np.random.default_rng(0).integers(0, 256, 300).astype(np.int32)
     want = M.forward(params, pcfg, {"tokens": jnp.asarray(toks)[None]},
                      q_chunk=100, kv_chunk=100)[0][0]
     at = np.array([0, 5, 150, 299])
-    got = reference.logits_at(params, arch, toks, at)
+    got = LLAMA.logits_at(params, arch, toks, at)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want)[at],
                                rtol=2e-4, atol=2e-4)
     # with a window, and in the control precisions, it differs
-    low = reference.logits_at(params, arch, toks, at, "fp8")
+    low = LLAMA.logits_at(params, arch, toks, at, "fp8")
     assert float(jnp.max(jnp.abs(low - got))) > 1e-3
-    win = reference.logits_at(params, dataclasses.replace(arch, window=16),
-                              toks, at)
+    win = LLAMA.logits_at(params, dataclasses.replace(arch, window=16),
+                          toks, at)
     assert float(jnp.max(jnp.abs(win[2:] - got[2:]))) > 1e-3
     np.testing.assert_allclose(np.asarray(win[:2]), np.asarray(got[:2]),
                                rtol=1e-5, atol=1e-5)

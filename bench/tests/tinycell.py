@@ -44,11 +44,12 @@ def make_bench(tmp: pathlib.Path, limit: float = 1.0) -> pathlib.Path:
     """A checkout-like tree under ``tmp`` whose ``bench/`` holds the
     repository's data files and the tiny ones; returns its ``bench``."""
     bench = tmp / "bench"
-    for sub in ("configs", "traffic", "workloads", "metrics"):
+    for sub in ("configs", "archs", "traffic", "workloads", "metrics"):
         shutil.copytree(BENCH / sub, bench / sub)
     shutil.copy(BENCH / "peaks.json", bench / "peaks.json")
     cfg = json.loads((BENCH / "configs" / "yi_6b.json").read_text())
     cfg["name"] = "tiny"
+    cfg["architecture"] = "llama_gqa"
     cfg["model"].update(hidden_size=64, intermediate_size=128,
                         num_attention_heads=4, num_key_value_heads=2,
                         num_hidden_layers=2, vocab_size=256, head_dim=16)
